@@ -1,9 +1,13 @@
-"""Kernel microbenchmarks: interpret-mode correctness + oracle wall-time.
+"""Kernel microbenchmarks: kernel-vs-oracle correctness + oracle wall-time.
 
-On this CPU host the Pallas kernels run in interpret mode, so wall-clock
-measures the ORACLE (jnp) path; the printed `derived` column is the max
-abs error of the kernel vs its oracle (the correctness contract that must
-hold before any TPU deployment).
+The kernels compile for the TPU; --interpret runs them through the Pallas
+interpreter instead, which is the only way they run on a CPU host.  The
+wall-clock column times the ORACLE (jnp) path, and is a host timing unless
+the run is on the chip; the printed `derived` column is the max abs error of
+the kernel vs its oracle (the correctness contract that must hold before any
+TPU deployment).
+
+  PYTHONPATH=src python benchmarks/kernels_bench.py --tiny --interpret
 """
 from __future__ import annotations
 
@@ -24,7 +28,7 @@ def _timeit(fn, *args, iters: int = 5) -> float:
     return (time.perf_counter() - t0) / iters * 1e6
 
 
-def main(tiny: bool = False) -> None:
+def main(tiny: bool = False, interpret: bool = False) -> None:
     key = jax.random.PRNGKey(0)
     rows = []
 
@@ -36,7 +40,8 @@ def main(tiny: bool = False) -> None:
     noise = jax.random.normal(ks[2], (d,))
     bias, eps = jnp.float32(0.1), jnp.float32(0.7)
     t = _timeit(ops.floa_aggregate_ref, coeffs, grads, noise, bias, eps)
-    got = ops.floa_aggregate(coeffs, grads, noise, bias, eps)
+    got = ops.floa_aggregate(coeffs, grads, noise, bias, eps,
+                             interpret=interpret)
     want = ops.floa_aggregate_ref(coeffs, grads, noise, bias, eps)
     rows.append((f"floa_aggregate_u16_d{dtag}", t,
                  float(jnp.max(jnp.abs(got - want)))))
@@ -50,13 +55,15 @@ def main(tiny: bool = False) -> None:
     bb = jax.random.normal(kb[3], (s_n,))
     be = jax.random.normal(kb[4], (s_n,))
     t = _timeit(ops.floa_aggregate_batched_ref, bc, bg, bz, bb, be)
-    got = ops.floa_aggregate_batched(bc, bg, bz, bb, be)
+    got = ops.floa_aggregate_batched(bc, bg, bz, bb, be,
+                                     interpret=interpret)
     want = ops.floa_aggregate_batched_ref(bc, bg, bz, bb, be)
     rows.append((f"floa_aggregate_batched_s{s_n}_u16_d{dtag}", t,
                  float(jnp.max(jnp.abs(got - want)))))
 
     t = _timeit(ops.grad_stats_ref, grads)
-    got, want = ops.grad_stats(grads), ops.grad_stats_ref(grads)
+    got = ops.grad_stats(grads, interpret=interpret)
+    want = ops.grad_stats_ref(grads)
     err = float(jnp.max(jnp.abs(got - want) / (jnp.abs(want) + 1.0)))  # relative
     rows.append((f"grad_stats_u16_d{dtag}", t, err))
 
@@ -67,7 +74,8 @@ def main(tiny: bool = False) -> None:
     pos = jnp.int32(s - 1)
     t = _timeit(ops.decode_attention_ref, q, k, v, pos)
     err = float(jnp.max(jnp.abs(
-        ops.decode_attention(q, k, v, pos) - ops.decode_attention_ref(q, k, v, pos))))
+        ops.decode_attention(q, k, v, pos, interpret=interpret)
+        - ops.decode_attention_ref(q, k, v, pos))))
     rows.append((f"decode_attention_b{b}_s{s}", t, err))
 
     for name, us, derived in rows:
@@ -78,4 +86,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="small shapes for CI smoke (interpret mode is slow)")
-    main(tiny=ap.parse_args().tiny)
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernels in the Pallas interpreter (CPU hosts)")
+    args = ap.parse_args()
+    main(tiny=args.tiny, interpret=args.interpret)

@@ -77,14 +77,10 @@ for the whole sweep).
 engine (ExecutionPlan(checkpoint_dir=...) committing the full resume carry
 at every chunk boundary) A/B'd against the plain chunked engine on the same
 grid — the warm-rows ratio is the checkpoint tax — plus the wall time of a
-`run(resume=True)` restoring off the latest committed boundary, and a
-persistent-compilation-cache warm-restart pair: two fresh subprocesses run
-the same tiny sweep against one $REPRO_COMPILATION_CACHE directory, the
-first populating it cold and the second restarting warm (the
-cache-hit path a resumed fleet takes).  Recorded under the JSON's "resume"
-key; the perf gate checks the chunked/chunked_ckpt warm rows shape-aware
-(lanes/rounds/chunk_rounds/dim must match the baseline, else skipped) and
-never gates the subprocess cache timings (they are machine-noise bound).
+`run(resume=True)` restoring off the latest committed boundary.  Recorded
+under the JSON's "resume" key; the perf gate checks the
+chunked/chunked_ckpt warm rows shape-aware (lanes/rounds/chunk_rounds/dim
+must match the baseline, else skipped).
 
 --workers benches the worker-population scaling series: the mixed-defense
 worker grid (analog FLOA + median / trimmed-mean / Krum lanes) at each U in
@@ -118,9 +114,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -481,58 +474,17 @@ def bench_lm(series, rounds: int, reps: int) -> dict:
     return out
 
 
-_CACHE_CHILD = r"""
-import sys, time
-import jax, jax.numpy as jnp, numpy as np
-from repro import setup_compilation_cache
-setup_compilation_cache(sys.argv[1], min_compile_time_secs=0)
-from repro.core import (AttackConfig, AttackType, ChannelConfig, FLOAConfig,
-                        PowerConfig, first_n_mask)
-from repro.fl import ScenarioCase, SweepEngine, SweepSpec
-
-d_in, d_h = 8, 4
-dim = d_in * d_h + d_h
-
-def loss(params, b):
-    pred = jax.nn.relu(b["x"] @ params["w1"]) @ params["w2"]
-    return jnp.mean((pred - b["y"]) ** 2)
-
-k = jax.random.PRNGKey(0)
-params = {"w1": jax.random.normal(k, (d_in, d_h)),
-          "w2": jax.random.normal(k, (d_h, 1))}
-u, rounds = 4, 4
-rng = np.random.default_rng(0)
-batches = {"x": rng.normal(size=(rounds, u, d_in)).astype(np.float32),
-           "y": rng.normal(size=(rounds, u, 1)).astype(np.float32)}
-cases = [ScenarioCase(
-    f"lane{i}",
-    FLOAConfig(channel=ChannelConfig(num_workers=u, sigma=1.0,
-                                     noise_std=0.05),
-               power=PowerConfig(num_workers=u, dim=dim, p_max=1.0),
-               attack=AttackConfig(
-                   attack=AttackType.STRONGEST if i % 2 else AttackType.NONE,
-                   byzantine_mask=first_n_mask(u, i % 2))),
-    0.05, seed=100 + i) for i in range(4)]
-t0 = time.perf_counter()
-SweepEngine(loss, SweepSpec.build(cases)).run(params, batches)
-print(f"SWEEP_ELAPSED {time.perf_counter() - t0:.4f}")
-"""
-
-
 def bench_resume(mc, shards, params, rounds: int, scenarios: int, reps: int,
                  chunk: int) -> dict:
-    """Preemption-safety machinery (--resume): checkpoint tax, resume
-    restore, and the persistent-compilation-cache warm restart.
+    """Preemption-safety machinery (--resume): checkpoint tax and resume
+    restore.
 
     `chunked` vs `chunked_ckpt` is the same chunked grid with and without
     a checkpoint_dir (every chunk boundary commits the full resume carry
     atomically) — the warm ratio is what preemption safety costs per
     round.  `resume_latest_s` times `run(resume=True)` restoring off the
     last committed boundary and finishing the run: the wall a preempted
-    fleet pays to get back to where it died.  The `cache` rows launch two
-    fresh subprocesses running an identical tiny sweep against one
-    compilation-cache dir — cold populates, warm restarts off the disk
-    cache — subprocess wall time, deliberately NOT gated."""
+    fleet pays to get back to where it died."""
     batches = FederatedSampler(shards, mc.batch_per_worker,
                                seed=1).stack_rounds(rounds)
     exps = grid(scenarios, rounds)
@@ -580,27 +532,6 @@ def bench_resume(mc, shards, params, rounds: int, scenarios: int, reps: int,
         print(f"# checkpoint tax (warm chunked_ckpt/chunked wall): "
               f"{out['checkpoint_tax']:.2f}x; resume off latest boundary: "
               f"{out['resume_latest_s']:.2f}s")
-    # Compilation-cache warm restart: same program, two fresh processes,
-    # one persistent cache dir.
-    with tempfile.TemporaryDirectory() as cache_dir:
-        env = dict(os.environ, REPRO_COMPILATION_CACHE=cache_dir)
-        walls = []
-        for phase in ("cold", "warm"):
-            t0 = time.perf_counter()
-            proc = subprocess.run([sys.executable, "-c", _CACHE_CHILD,
-                                   cache_dir], env=env, capture_output=True,
-                                  text=True, timeout=600)
-            walls.append(time.perf_counter() - t0)
-            if proc.returncode != 0:
-                print(f"# cache {phase} subprocess failed:\n{proc.stderr}")
-                out["cache"] = dict(error=f"{phase} subprocess failed")
-                return out
-        out["cache"] = dict(
-            cold_s=round(walls[0], 2), warm_s=round(walls[1], 2),
-            warm_restart_speedup=round(walls[0] / walls[1], 3))
-        print(f"# compilation cache: cold {out['cache']['cold_s']:.1f}s, "
-              f"warm restart {out['cache']['warm_s']:.1f}s "
-              f"({out['cache']['warm_restart_speedup']:.2f}x)")
     return out
 
 
@@ -681,8 +612,6 @@ def check_regressions(fresh: dict, baseline: dict,
             for sub in ("chunked", "chunked_ckpt"):
                 if sub in b_res and sub in f_res:
                     gate("resume", sub, f_res[sub], b_res[sub])
-            # The subprocess cache timings are machine-noise bound and
-            # never gated.
     for name, b_row in (baseline.get("workers") or {}).items():
         f_row = (fresh.get("workers") or {}).get(name)
         if f_row is None:
@@ -980,8 +909,7 @@ if __name__ == "__main__":
     ap.add_argument("--resume", action="store_true",
                     help="also bench the preemption-safety machinery: "
                          "checkpointed-chunked vs plain-chunked warm "
-                         "throughput, resume-restore wall, and the "
-                         "compilation-cache cold/warm subprocess restart")
+                         "throughput and resume-restore wall")
     ap.add_argument("--resume-rounds", type=int, default=10,
                     help="rounds for the --resume checkpoint A/B grid")
     ap.add_argument("--resume-lanes", type=int, default=8,

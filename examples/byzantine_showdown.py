@@ -176,7 +176,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
-    setup_compilation_cache()  # no-op unless $REPRO_COMPILATION_CACHE is set
+    setup_compilation_cache()  # $JAX_COMPILATION_CACHE_DIR, else .jax_cache
 
     mc, sampler, xt, yt = setup(args.dirichlet)
     eval_fn = lambda p: {"accuracy": mlp_accuracy(p, xt, yt)}

@@ -31,8 +31,9 @@ from repro.models import init_mlp, mlp_accuracy, mlp_loss
 
 SMOKE = bool(os.environ.get("REPRO_SMOKE"))
 
-# Persistent XLA compilation cache (no-op unless $REPRO_COMPILATION_CACHE is
-# set): a restarted demo skips the sweep recompile.  See docs/checkpointing.md.
+# Persistent XLA compilation cache ($JAX_COMPILATION_CACHE_DIR, else the
+# checkout's .jax_cache): a restarted demo skips the sweep recompile.  See
+# docs/checkpointing.md.
 setup_compilation_cache()
 
 

@@ -6,10 +6,10 @@ token stream for R FLOA rounds as ONE compiled sweep: three scenario lanes
 (clean BEV, sign-flip attack, median screening of the same attack) share the
 [S, D] flat state and run in a single `SweepEngine` dispatch.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    PYTHONPATH=src python examples/train_floa_lm.py --rounds 20
+  PYTHONPATH=src python examples/train_floa_lm.py --rounds 20
 
-  # ("model",)-sharded big-D state over 4 fake devices:
+  # ("model",)-sharded big-D state over 4 devices (4 TPU chips, or on a
+  # CPU host XLA_FLAGS=--xla_force_host_platform_device_count=4):
   python examples/train_floa_lm.py --model-shards 4
 
   # Preemption-safe: checkpoint at chunk boundaries, rerun with --resume.
@@ -17,13 +17,6 @@ token stream for R FLOA rounds as ONE compiled sweep: three scenario lanes
 
 --smoke shrinks the model to D ~ 70k for a seconds-scale CPU sanity pass.
 """
-import os
-
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=8 "
-        + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import dataclasses
 import time

@@ -208,14 +208,15 @@ def batched_floa_combine(
     bias: Array,
     eps: Array,
     use_kernel: Optional[bool] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Array:
     """[S, U, D] OTA combine: out[s] = coeffs[s] @ flat[s] + bias[s] + eps[s] z[s].
 
-    The sweep engine's hot spot.  Routed through the fused Pallas kernel when
-    the flattened gradient is large and the backend compiles it natively
-    (TPU); the einsum reference otherwise — on CPU hosts the kernel only runs
-    in interpret mode, which is for correctness tests, not speed.
+    The sweep engine's hot spot.  use_kernel=None routes through the fused
+    Pallas kernel when the flattened gradient is large and the backend is
+    the TPU, and through the einsum reference otherwise.  A requested kernel
+    compiles for the TPU unless interpret=True asks for the Pallas
+    interpreter (correctness tests on CPU hosts).
     """
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
@@ -237,7 +238,7 @@ def batched_floa_step(
     bias: Array,
     eps: Array,
     use_kernel: Optional[bool] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[Array, Array]:
     """Fused [S, U, D] OTA combine + PS update (eq. 7 + eq. 8), flat state.
 

@@ -76,8 +76,8 @@ def _flatten_u(grads_u):
 
 # --------------------------------------------------------- flat [U, D] kernels
 
-# Below this flat size (or off-TPU, where Pallas only interprets) jnp.sort's
-# generic lowering is fine; above it the sorting-network kernels
+# Below this flat size (or off the TPU, where Mosaic kernels cannot run)
+# jnp.sort's generic lowering is fine; above it the sorting-network kernels
 # (kernels/defense_sort.py) sort the [U, TILE] block in one VMEM pass.
 SORT_KERNEL_MIN_D = 1 << 14
 # Worker-axis routing: up to this U the fully-unrolled odd-even network is
@@ -89,7 +89,7 @@ SORT_UNROLL_MAX_U = 32
 
 
 def sorted_columns(flat: Array, use_kernel: Optional[bool] = None,
-                   interpret: Optional[bool] = None) -> Array:
+                   interpret: bool = False) -> Array:
     """Ascending per-coordinate sort over the worker axis — the screening
     primitive coordinate-median and trimmed-mean share.  Routed to a Pallas
     sorting-network kernel on TPU at large D (same routing contract as
@@ -272,12 +272,15 @@ def flat_geometric_median(flat: Array, iters: int = 8,
 #
 # K-of-U client sampling (fl/sweep.py): non-participating workers never report
 # a gradient, so every screening defense must run on the participating rows
-# only.  Each masked kernel reduces BITWISE to its unmasked twin at a full
-# mask (the K=U == full-participation sweep contract): selects with an
-# all-True mask are identity, counts equal the static U, and means are
-# rescaled by exactly-1.0 (mean * (U/count)) instead of re-divided — a
-# sum/traced-count spelling would round differently from jnp.mean under jit
-# (XLA strength-reduces the divide-by-constant into a reciprocal multiply).
+# only.  Each masked kernel reduces to its unmasked twin at a full mask (the
+# full-participation lanes of a mixed sweep): selects with an all-True mask
+# are identity, counts equal the static U, and means are rescaled by
+# exactly-1.0 (mean * (U/count)) instead of re-divided — a sum/traced-count
+# spelling would round differently from jnp.mean under jit (XLA
+# strength-reduces the divide-by-constant into a reciprocal multiply).
+# Inside a whole fused sweep XLA may still order a masked reduction
+# differently, so full lanes of a mixed grid are pinned to a few ulp, not
+# bitwise (tests/test_scenario_axes.py).
 
 
 def flat_masked_mean(flat: Array, mask: Array) -> Array:

@@ -196,9 +196,8 @@ def from_floa(cfg, alpha: float,
 
     participants: optional K for K-of-U per-round client sampling (the sweep
     engine draws the round's K participants from the lane key); None means
-    full participation.  K = U is a valid — bitwise-pinned — degenerate case
-    but still exercises the masked machinery, which is exactly what the
-    K=U == full-participation contract tests.
+    full participation, and so is K = U (the sweep engine traces no masking
+    op unless some lane samples K < U).
     """
     cfg.validate()
     u = cfg.num_workers

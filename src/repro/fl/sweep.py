@@ -78,7 +78,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import channel as CH
@@ -139,9 +138,9 @@ class ScenarioCase:
     participants selects K-of-U per-round client sampling: each round the
     lane draws K participants from its own key stream (non-participants
     transmit nothing; digital defenses screen the K participating rows
-    only).  None (default) is full participation with zero masking ops
-    traced; participants=U runs the masked machinery and is pinned bitwise
-    equal to None (tests/test_scenario_axes.py).
+    only).  None (default) and participants=U are full participation:
+    unless another lane samples K < U, no masking op is traced and the two
+    are bitwise equal (tests/test_scenario_axes.py).
     """
 
     name: str
@@ -303,11 +302,13 @@ class SweepSpec:
 
     @property
     def any_partial(self) -> bool:
-        """K-of-U participation: any lane with participants set.  NOTE an
-        explicit participants=U still counts — it runs the masked machinery,
-        which is exactly what the K=U == full-participation bitwise contract
-        exercises."""
-        return any(c.participants is not None for c in self.cases)
+        """K-of-U participation: any lane sampling K < U clients.  A lane
+        with participants=U is full participation and does not count, so a
+        sweep whose lanes are all None or U traces the unmasked program and
+        is bitwise equal to participants=None."""
+        u = self.num_workers
+        return any(c.participants is not None and c.participants < u
+                   for c in self.cases)
 
     @property
     def any_directional(self) -> bool:
@@ -1663,20 +1664,20 @@ class SweepEngine:
                               else w_spec)
             else:
                 state_spec = lane
-            run = shard_map(
+            run = jax.shard_map(
                 run, mesh=self.mesh,
                 in_specs=(state_spec, lane, rep, lane),
                 out_specs=(state_spec, lane_t, lane_t, lane_t),
-                check_rep=False)
+                check_vma=False)
             # The chunk program additionally threads the raw (state, keys)
             # carry out (lane-sharded) and takes the replicated scalar
             # t0 / rounds_total pair; finalize runs OUTSIDE the shard_map
             # (vmap over lanes, sharding propagates through jit).
-            chunk = shard_map(
+            chunk = jax.shard_map(
                 chunk, mesh=self.mesh,
                 in_specs=(state_spec, lane, rep, rep, rep, lane),
                 out_specs=(state_spec, lane, lane_t, lane_t, lane_t),
-                check_rep=False)
+                check_vma=False)
         if final is None:
             self._run_jit = jax.jit(run)
         else:

@@ -29,10 +29,19 @@ Shape contract and tiling mirror `floa_aggregate`:
                                            U_pad <= BITONIC_MAX_U)
 
 Grid is (D // TILE); the [U(_pad), TILE] block lives in VMEM (unrolled:
-U<=32 x TILE_D=2048 f32 = 256 KiB; bitonic: the tile narrows as U_pad grows
-— `bitonic_tile_d` keeps block x ~3 live temporaries inside the ~16 MiB
-budget, bottoming out at the 128-lane minimum tile, which is what caps
-U_pad at BITONIC_MAX_U=8192).  D is padded to the tile once, in the
+U<=32 x TILE_D=2048 f32 = 256 KiB per block).  The bitonic kernel's scoped
+VMEM, as the v5e compiler reserves it, is about 16 blocks: the input and
+output blocks double-buffered plus the stage body's temporaries (compiles
+for v5e measured 9 blocks at [1024, 512] and 15.75 at [4096, 128] and
+[8192, 128]).  `bitonic_tile_d` narrows the tile as U_pad grows so those
+blocks stay within 16 MiB, and `bitonic_vmem_limit` asks for the blocks
+plus a quarter of headroom, never less than the 16 MiB default.  Past the
+128-lane floor (U_pad >= 4096) the tile cannot narrow and the limit grows
+with U_pad, which caps U_pad at BITONIC_MAX_U=8192 (an 80 MiB limit of
+v5e's 128 MiB VMEM; the next power of two would need 160 MiB).  The
+unrolled network's blocks stay far below the default and it sets no
+limit.  D is
+padded to the tile once, in the
 un-jitted public wrappers, before the jitted pallas_call core (columns sort
 independently, so zero-padded columns cannot perturb real ones; see the
 D-padding recursion note in floa_aggregate.py).
@@ -56,6 +65,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -64,9 +74,13 @@ TILE_D = 2048
 # pairs); larger slabs route to the bitonic kernel or the jnp.sort oracle.
 UNROLL_MAX_U = 32
 # Largest padded U the bitonic kernel accepts: at the 128-lane minimum tile
-# an [8192, 128] f32 block is 4 MiB, and the stage body keeps ~3 such
-# temporaries live — beyond this the block cannot fit VMEM at any tile.
+# an [8192, 128] f32 block is 4 MiB and the kernel holds about 16 of them
+# (see the module docstring) — twice that U would not fit v5e's VMEM.
 BITONIC_MAX_U = 8192
+# v5e's default scoped-VMEM limit, and the [u_pad, tile] f32 blocks the
+# bitonic kernel holds in it per grid step (measured, see module docstring).
+_SCOPED_VMEM = 16 << 20
+_BITONIC_LIVE_BLOCKS = 16
 
 
 def _pad_last(x: Array, pad: int) -> Array:
@@ -111,6 +125,7 @@ def _sort_columns_core(x: Array, interpret: bool, tile_d: int) -> Array:
         in_specs=[pl.BlockSpec((u, tile_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((u, tile_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((u, d), x.dtype),
+        name="sort_columns",
         interpret=interpret,
     )(x)
 
@@ -139,10 +154,17 @@ def sort_columns(x: Array, interpret: bool = False,
 
 
 def bitonic_tile_d(u_pad: int) -> int:
-    """Widest D tile whose [u_pad, tile] f32 block (x ~3 live stage
-    temporaries) stays inside the VMEM budget, floored at the 128-lane
-    minimum tile."""
-    return max(128, min(TILE_D, (1 << 19) // u_pad))
+    """Widest D tile whose live [u_pad, tile] f32 blocks fit the default
+    scoped VMEM, floored at the 128-lane minimum tile."""
+    fit = _SCOPED_VMEM // (_BITONIC_LIVE_BLOCKS * 4 * u_pad)
+    return max(128, min(TILE_D, fit))
+
+
+def bitonic_vmem_limit(u_pad: int, tile_d: int) -> int:
+    """Scoped-VMEM limit for one bitonic grid step: the live blocks plus a
+    quarter for headroom, never below the default."""
+    need = _BITONIC_LIVE_BLOCKS * 4 * u_pad * tile_d
+    return max(_SCOPED_VMEM, need + need // 4)
 
 
 def _bitonic_stages(x: Array) -> Array:
@@ -199,6 +221,9 @@ def _sort_columns_bitonic_core(x: Array, interpret: bool,
         in_specs=[pl.BlockSpec((u, tile_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((u, tile_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((u, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=bitonic_vmem_limit(u, tile_d)),
+        name="sort_columns_bitonic",
         interpret=interpret,
     )(x)
 
@@ -217,8 +242,8 @@ def sort_columns_bitonic(x: Array, interpret: bool = False,
     if u_pad > BITONIC_MAX_U:
         raise ValueError(
             f"sort_columns_bitonic: padded U={u_pad} exceeds "
-            f"BITONIC_MAX_U={BITONIC_MAX_U} (the [U_pad, 128] block no "
-            f"longer fits VMEM) — use the jnp.sort oracle")
+            f"BITONIC_MAX_U={BITONIC_MAX_U} (its [U_pad, 128] blocks no "
+            f"longer fit VMEM) — use the jnp.sort oracle")
     tile_d = tile_d or bitonic_tile_d(u_pad)
     dpad = -d % tile_d
     xp = _pad_last(x, dpad)

@@ -15,8 +15,13 @@ dominate, and the parameter row is read/written exactly once.
 
 Tiling: grid over D in TILE_D (=2048, a multiple of the 128-lane VPU width)
 steps; the [U, TILE_D] slab plus coefficient vector live in VMEM.  For
-U<=32, TILE_D=2048, bf16: 32*2048*2 = 128 KiB slab — comfortably inside the
-~16 MiB VMEM budget with double-buffering.
+U<=32, TILE_D=2048, f32: 32*2048*4 = 256 KiB slab, 512 KiB double-buffered,
+plus a few [1, TILE_D] rows (each padded to 8 sublanes, 64 KiB) — well
+inside v5e's 16 MiB default scoped VMEM.  The batched kernels view their
+[S, ...] operands with a unit middle axis and squeeze the scenario axis out
+of every block, so each block's last two dims are either whole or
+(1, TILE_D), as Mosaic requires (tests/test_tpu_compile.py compiles them
+for v5e at real width).
 
 D-padding happens once, in the un-jitted public wrappers, before the jitted
 pallas_call core is entered (an earlier version recursed back into the jitted
@@ -55,27 +60,43 @@ def _kernel(scal_ref, coeff_ref, g_ref, z_ref, o_ref):
 
 
 def _batched_kernel(scal_ref, coeff_ref, g_ref, z_ref, o_ref):
-    s = coeff_ref[:].astype(jnp.float32)            # [1, U] scenario row
-    g = g_ref[:].astype(jnp.float32)                # [1, U, TILE_D]
-    z = z_ref[:].astype(jnp.float32)                # [1, TILE_D]
+    # One (scenario, D tile) grid step; the scenario axis is squeezed out of
+    # every block, so rows arrive as [1, TILE_D] and coefficients as [U, 1].
+    s = coeff_ref[...]                              # [U, 1] scenario coeffs
+    g = g_ref[...].astype(jnp.float32)              # [U, TILE_D]
+    z = z_ref[...].astype(jnp.float32)              # [1, TILE_D]
     bias = scal_ref[0, 0]
     eps = scal_ref[0, 1]
-    acc = jnp.sum(s[0, :, None] * g[0], axis=0)     # VPU reduce over workers
-    o_ref[:] = (acc + bias + eps * z[0])[None].astype(o_ref.dtype)
+    acc = jnp.sum(s * g, axis=0, keepdims=True)     # VPU reduce over workers
+    o_ref[...] = (acc + bias + eps * z).astype(o_ref.dtype)
 
 
 def _batched_step_kernel(scal_ref, coeff_ref, w_ref, g_ref, z_ref,
                          wo_ref, go_ref):
-    s = coeff_ref[:].astype(jnp.float32)            # [1, U] scenario row
-    w = w_ref[:].astype(jnp.float32)                # [1, TILE_D] params
-    g = g_ref[:].astype(jnp.float32)                # [1, U, TILE_D]
-    z = z_ref[:].astype(jnp.float32)                # [1, TILE_D]
+    s = coeff_ref[...]                              # [U, 1] scenario coeffs
+    w = w_ref[...].astype(jnp.float32)              # [1, TILE_D] params
+    g = g_ref[...].astype(jnp.float32)              # [U, TILE_D]
+    z = z_ref[...].astype(jnp.float32)              # [1, TILE_D]
     bias = scal_ref[0, 0]
     eps = scal_ref[0, 1]
     alpha = scal_ref[0, 2]
-    gagg = jnp.sum(s[0, :, None] * g[0], axis=0) + bias + eps * z[0]
-    go_ref[:] = gagg[None].astype(go_ref.dtype)
-    wo_ref[:] = (w[0] - alpha * gagg)[None].astype(wo_ref.dtype)
+    gagg = jnp.sum(s * g, axis=0, keepdims=True) + bias + eps * z
+    go_ref[...] = gagg.astype(go_ref.dtype)
+    wo_ref[...] = (w - alpha * gagg).astype(wo_ref.dtype)
+
+
+def _batched_specs(u: int, tile_d: int, n_scal: int):
+    """BlockSpecs for the batched kernels over [S, ...] operands viewed with
+    a unit middle axis, so every block's last two dims are either full
+    (scalars [1, n_scal], coefficients [U, 1], slab rows [U, ...]) or
+    (1, TILE_D) — the shapes Mosaic accepts (a last-two block dim must
+    divide by (8, 128) or equal the array's dim).  The leading scenario dim
+    is squeezed (None)."""
+    scal = pl.BlockSpec((None, 1, n_scal), lambda s, i: (s, 0, 0))
+    coeff = pl.BlockSpec((None, u, 1), lambda s, i: (s, 0, 0))
+    slab = pl.BlockSpec((None, u, tile_d), lambda s, i: (s, 0, i))
+    row = pl.BlockSpec((None, 1, tile_d), lambda s, i: (s, 0, i))
+    return scal, coeff, slab, row
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_d"))
@@ -85,20 +106,18 @@ def _floa_aggregate_batched_core(coeffs: Array, grads: Array, noise: Array,
     s_n, u, d = grads.shape
     assert d % tile_d == 0, "core requires pre-padded D (see public wrapper)"
     scal = jnp.stack([bias.astype(jnp.float32),
-                      eps.astype(jnp.float32)], axis=1)  # [S, 2]
-    return pl.pallas_call(
+                      eps.astype(jnp.float32)], axis=1)[:, None]  # [S, 1, 2]
+    scal_spec, coeff_spec, slab_spec, row_spec = _batched_specs(u, tile_d, 2)
+    out = pl.pallas_call(
         _batched_kernel,
         grid=(s_n, d // tile_d),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda s, i: (s, 0)),          # scalar row
-            pl.BlockSpec((1, u), lambda s, i: (s, 0)),          # coeff row
-            pl.BlockSpec((1, u, tile_d), lambda s, i: (s, 0, i)),  # grad slab
-            pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),     # noise row
-        ],
-        out_specs=pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),
-        out_shape=jax.ShapeDtypeStruct((s_n, d), grads.dtype),
+        in_specs=[scal_spec, coeff_spec, slab_spec, row_spec],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((s_n, 1, d), grads.dtype),
+        name="floa_aggregate_batched",
         interpret=interpret,
-    )(scal, coeffs.astype(jnp.float32), grads, noise)
+    )(scal, coeffs.astype(jnp.float32)[:, :, None], grads, noise[:, None])
+    return out[:, 0]
 
 
 def floa_aggregate_batched(coeffs: Array, grads: Array, noise: Array,
@@ -130,27 +149,22 @@ def _floa_step_batched_core(w: Array, coeffs: Array, grads: Array,
     assert d % tile_d == 0, "core requires pre-padded D (see public wrapper)"
     scal = jnp.stack([bias.astype(jnp.float32),
                       eps.astype(jnp.float32),
-                      alpha.astype(jnp.float32)], axis=1)  # [S, 3]
-    return pl.pallas_call(
+                      alpha.astype(jnp.float32)], axis=1)[:, None]  # [S, 1, 3]
+    scal_spec, coeff_spec, slab_spec, row_spec = _batched_specs(u, tile_d, 3)
+    w_new, gagg = pl.pallas_call(
         _batched_step_kernel,
         grid=(s_n, d // tile_d),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda s, i: (s, 0)),          # scalar row
-            pl.BlockSpec((1, u), lambda s, i: (s, 0)),          # coeff row
-            pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),     # param row
-            pl.BlockSpec((1, u, tile_d), lambda s, i: (s, 0, i)),  # grad slab
-            pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),     # noise row
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),     # new params
-            pl.BlockSpec((1, tile_d), lambda s, i: (s, i)),     # aggregate
-        ],
+        in_specs=[scal_spec, coeff_spec, row_spec, slab_spec, row_spec],
+        out_specs=[row_spec, row_spec],             # new params, aggregate
         out_shape=[
-            jax.ShapeDtypeStruct((s_n, d), w.dtype),
-            jax.ShapeDtypeStruct((s_n, d), grads.dtype),
+            jax.ShapeDtypeStruct((s_n, 1, d), w.dtype),
+            jax.ShapeDtypeStruct((s_n, 1, d), grads.dtype),
         ],
+        name="floa_step_batched",
         interpret=interpret,
-    )(scal, coeffs.astype(jnp.float32), w, grads, noise)
+    )(scal, coeffs.astype(jnp.float32)[:, :, None], w[:, None], grads,
+      noise[:, None])
+    return w_new[:, 0], gagg[:, 0]
 
 
 def floa_step_batched(w: Array, coeffs: Array, grads: Array, noise: Array,
@@ -199,6 +213,7 @@ def _floa_aggregate_core(coeffs: Array, grads: Array, noise: Array,
         ],
         out_specs=pl.BlockSpec((tile_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((d,), grads.dtype),
+        name="floa_aggregate",
         interpret=interpret,
     )(scal, coeffs, grads, noise)
 

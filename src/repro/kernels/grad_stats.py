@@ -47,5 +47,6 @@ def grad_stats(grads: Array, interpret: bool = False,
         in_specs=[pl.BlockSpec((u, tile_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((u, 2), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((u, 2), jnp.float32),
+        name="grad_stats",
         interpret=interpret,
     )(grads)

@@ -1,7 +1,10 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On CPU hosts (this container) `interpret=True` executes the kernel bodies in
-Python for correctness validation; on TPU the same calls compile to Mosaic.
+Every entry compiles its kernel to Mosaic for the TPU.  `interpret=True`
+runs the kernel body through the Pallas interpreter instead, on any backend;
+only callers that ask for it get it (the CPU test suite, the kernel
+microbenchmark's --interpret mode), so a kernel never silently stops running
+on the device.
 """
 from __future__ import annotations
 
@@ -26,57 +29,46 @@ from repro.kernels.grad_stats import grad_stats as _grad_stats
 Array = jax.Array
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def floa_aggregate(coeffs, grads, noise, bias, eps, interpret=None) -> Array:
-    interpret = _interpret_default() if interpret is None else interpret
+def floa_aggregate(coeffs, grads, noise, bias, eps, interpret=False) -> Array:
     return _floa_aggregate(coeffs, grads, noise, jnp.asarray(bias),
                            jnp.asarray(eps), interpret=interpret)
 
 
 def floa_aggregate_batched(coeffs, grads, noise, bias, eps,
-                           interpret=None) -> Array:
-    interpret = _interpret_default() if interpret is None else interpret
+                           interpret=False) -> Array:
     return _floa_aggregate_batched(coeffs, grads, noise, jnp.asarray(bias),
                                    jnp.asarray(eps), interpret=interpret)
 
 
 def floa_step_batched(w, coeffs, grads, noise, bias, eps, alpha,
-                      interpret=None):
+                      interpret=False):
     """Fused [S, U, D] combine + PS update; returns (w_new, gagg)."""
-    interpret = _interpret_default() if interpret is None else interpret
     return _floa_step_batched(w, coeffs, grads, noise, jnp.asarray(bias),
                               jnp.asarray(eps), jnp.asarray(alpha),
                               interpret=interpret)
 
 
-def sort_columns(x, interpret=None) -> Array:
+def sort_columns(x, interpret=False) -> Array:
     """[U, D] ascending sort along the worker axis (odd-even network,
     U <= UNROLL_MAX_U).  Batched use goes through `jax.vmap` (Pallas lifts
     it into a leading grid dimension); `sort_columns_batched_ref` is that
     route's oracle."""
-    interpret = _interpret_default() if interpret is None else interpret
     return _sort_columns(x, interpret=interpret)
 
 
-def sort_columns_bitonic(x, interpret=None) -> Array:
+def sort_columns_bitonic(x, interpret=False) -> Array:
     """[U, D] ascending sort along the worker axis — the large-U successor
     to `sort_columns`: O(log^2 U) bitonic stages instead of an O(U^2)
     unrolled network, U padded to a power of two (<= BITONIC_MAX_U).  Same
     oracle (`sort_columns_ref`) and vmap route as `sort_columns`."""
-    interpret = _interpret_default() if interpret is None else interpret
     return _sort_columns_bitonic(x, interpret=interpret)
 
 
-def grad_stats(grads, interpret=None) -> Array:
-    interpret = _interpret_default() if interpret is None else interpret
+def grad_stats(grads, interpret=False) -> Array:
     return _grad_stats(grads, interpret=interpret)
 
 
-def decode_attention(q, k, v, pos, interpret=None) -> Array:
-    interpret = _interpret_default() if interpret is None else interpret
+def decode_attention(q, k, v, pos, interpret=False) -> Array:
     return _decode_attention(q, k, v, pos, interpret=interpret)
 
 

@@ -23,41 +23,30 @@ processes ("Multiprocess computations aren't implemented on the CPU
 backend"); we switch it to gloo BEFORE initialize, which is what makes
 the 2-process CI smoke real.
 
-`setup_compilation_cache` points `jax.experimental.compilation_cache` at
-a persistent directory (argument, else $REPRO_COMPILATION_CACHE) so a
-restarted/resumed fleet skips recompiles — the other half of
+`setup_compilation_cache` turns on JAX's persistent compilation cache so
+a restarted/resumed fleet skips recompiles — the other half of
 preemption-safe sweeps next to the engine's chunk-boundary checkpoints.
+Where $JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and nothing is
+set in code; otherwise the cache lives at one fixed path in the checkout,
+`DEFAULT_CACHE_DIR`.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 from typing import Optional
 
 import numpy as np
 
 import jax
 
-#: Environment variable naming the persistent compilation-cache directory.
-CACHE_ENV = "REPRO_COMPILATION_CACHE"
-
-
-def _already_initialized() -> bool:
-    """Whether the jax distributed runtime is already up.  Prefers the
-    public `jax.distributed.is_initialized` (jax >= 0.4.34); falls back to
-    the internal global_state on older versions, and to False when neither
-    is readable — `initialize` itself then raises if called twice, which
-    beats an ImportError at module import time."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        try:
-            return bool(is_init())
-        except Exception:
-            pass
-    try:
-        from jax._src.distributed import global_state
-        return global_state.coordinator_address is not None
-    except Exception:
-        return False
+#: Environment variable JAX reads its compilation-cache directory from.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: The cache's one fixed home in the checkout when $JAX_COMPILATION_CACHE_DIR
+#: is unset.  It never moves between runs: a cache is only found again at
+#: the path that wrote it.
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                        / ".jax_cache")
 
 
 def initialize_distributed(coordinator_address: Optional[str] = None,
@@ -81,7 +70,7 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     jax refuses to bootstrap after any computation has executed, and even
     `jax.process_count()` would count as one.
     """
-    if _already_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     if num_processes == 1:
         return False
@@ -90,13 +79,8 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
             and "JAX_COORDINATOR_ADDRESS" not in os.environ):
         return False                      # single-process job, nothing to do
     # The default CPU collectives cannot cross processes; gloo can.  Must
-    # be set before initialize; a no-op for non-CPU backends (and the
-    # config may not exist on every jax version — then CPU multi-process
-    # is unsupported anyway).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:
-        pass
+    # be set before initialize; a no-op for non-CPU backends.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id,
@@ -104,28 +88,19 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     return jax.process_count() > 1
 
 
-def setup_compilation_cache(cache_dir: Optional[str] = None,
-                            min_compile_time_secs: Optional[float] = None
-                            ) -> Optional[str]:
-    """Enable the persistent XLA compilation cache.
+def setup_compilation_cache() -> str:
+    """Enable JAX's persistent compilation cache; returns its directory.
 
-    cache_dir=None reads $REPRO_COMPILATION_CACHE; when that is unset too,
-    this is a no-op returning None (so entry points can call it
-    unconditionally).  min_compile_time_secs lowers jax's "don't bother
-    caching fast compiles" threshold — pass 0 to cache everything, which
-    the warm-restart benchmark needs for its deliberately tiny programs.
-    Returns the cache directory in use.
+    With $JAX_COMPILATION_CACHE_DIR set this sets nothing: JAX reads that
+    variable itself, and the cache goes there and nowhere else.  Unset, the
+    cache goes to `DEFAULT_CACHE_DIR` (`.jax_cache` at the checkout root,
+    which git ignores).  Entry points call it before their first compile.
     """
-    cache_dir = cache_dir if cache_dir is not None else os.environ.get(
-        CACHE_ENV)
-    if not cache_dir:
-        return None
-    from jax.experimental import compilation_cache as cc
-    cc.compilation_cache.set_cache_dir(cache_dir)
-    if min_compile_time_secs is not None:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
-    return cache_dir
+    env_dir = os.environ.get(CACHE_ENV)
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
 def fetch(x):

@@ -29,8 +29,9 @@ from repro.launch.steps import init_floa_state, init_model, make_train_step
 
 
 def main() -> None:
-    # Multi-host fleets: both are env-driven no-ops on a plain single-process
-    # launch (JAX_COORDINATOR_ADDRESS / REPRO_COMPILATION_CACHE unset).
+    # Multi-host bootstrap is a no-op on a plain single-process launch
+    # (JAX_COORDINATOR_ADDRESS unset); the compile cache goes to
+    # $JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache.
     initialize_distributed()
     setup_compilation_cache()
     ap = argparse.ArgumentParser()

@@ -75,22 +75,17 @@ def _resume_row(chunked=100.0, ckpt=90.0, lanes=8, rounds=10,
     return {"lanes": lanes, "rounds": rounds, "chunk_rounds": chunk_rounds,
             "dim": dim,
             "chunked": {"warm_rounds_per_sec": chunked},
-            "chunked_ckpt": {"warm_rounds_per_sec": ckpt},
-            "cache": {"cold_s": 10.0, "warm_s": 1.0,
-                      "warm_restart_speedup": 10.0}}
+            "chunked_ckpt": {"warm_rounds_per_sec": ckpt}}
 
 
 def test_gate_resume_rows():
     """The resume section gates its chunked/chunked_ckpt warm rows
-    shape-aware (lanes/rounds/chunk_rounds/dim) and never gates the
-    subprocess cache timings."""
+    shape-aware (lanes/rounds/chunk_rounds/dim)."""
     base = _rec(engines={"flat": 100.0})
     base["resume"] = _resume_row()
-    # within tolerance, cache wildly slower: passes (cache is not gated)
+    # within tolerance: passes
     fresh = _rec(engines={"flat": 100.0})
     fresh["resume"] = _resume_row(chunked=51.0, ckpt=46.0)
-    fresh["resume"]["cache"] = {"cold_s": 10.0, "warm_s": 10.0,
-                                "warm_restart_speedup": 1.0}
     fails, notes = check_regressions(fresh, base, tolerance=0.5)
     assert fails == [] and notes == []
     # a collapsed checkpointed row fails

@@ -137,7 +137,6 @@ def test_seqsharded_decode_partial_softmax():
     matches the single-device reference exactly."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.models.attention import decode_local_partial, combine_partials
         from repro.kernels.ref import decode_attention_ref
@@ -155,10 +154,10 @@ def test_seqsharded_decode_partial_softmax():
                                      (q_loc.shape[0], sloc))
             m, l, acc = decode_local_partial(q_loc, k_loc, v_loc, valid)
             return combine_partials(m, l, acc, ("model",))
-        f = shard_map(inner, mesh=mesh,
-                      in_specs=(P(), P(None, "model", None, None),
-                                P(None, "model", None, None)),
-                      out_specs=P(), check_rep=False)
+        f = jax.shard_map(inner, mesh=mesh,
+                          in_specs=(P(), P(None, "model", None, None),
+                                    P(None, "model", None, None)),
+                          out_specs=P(), check_vma=False)
         got = f(q, k, v)
         want = decode_attention_ref(q, k, v, jnp.int32(pos))
         err = float(jnp.max(jnp.abs(got - want.astype(jnp.float32))))

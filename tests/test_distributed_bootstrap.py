@@ -24,17 +24,25 @@ def test_initialize_distributed_single_process_noop():
     assert jax.process_count() == 1
 
 
-def test_setup_compilation_cache_noop_without_dir(monkeypatch):
-    monkeypatch.delenv("REPRO_COMPILATION_CACHE", raising=False)
-    assert setup_compilation_cache() is None
-
-
-def test_setup_compilation_cache_sets_dir(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_COMPILATION_CACHE", str(tmp_path))
+def test_setup_compilation_cache_noop_without_dir(tmp_path, monkeypatch):
+    """With $JAX_COMPILATION_CACHE_DIR set, JAX owns the cache directory:
+    the helper reports it and sets nothing in code."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
     assert setup_compilation_cache() == str(tmp_path)
-    # Explicit argument beats the environment.
-    other = tmp_path / "other"
-    assert setup_compilation_cache(str(other)) == str(other)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_setup_compilation_cache_sets_dir(monkeypatch):
+    """Unset, the cache goes to the one fixed path in the checkout."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert setup_compilation_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 @pytest.mark.slow

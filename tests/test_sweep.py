@@ -249,19 +249,26 @@ def test_vmapped_grid_matches_singles():
 
 def test_sweep_matches_looped_trainer():
     """One sweep lane == the looped FLTrainer on the same config and key
-    (noiseless so the per-leaf vs flattened noise layouts cannot differ)."""
+    (noiseless so the per-leaf vs flattened noise layouts cannot differ).
+
+    The two programs compute the same math but XLA fuses them differently,
+    so their f32 reductions (batch mean, worker stats, the combine) may sum
+    in another order and each round can move the loss by an ulp or two that
+    later rounds carry on.  The bound is 4 ulp per round; the worst lane
+    measured 9 ulp after 6 rounds."""
     loss, params, dim, batches = _tiny_problem(rounds=6)
+    rounds = batches["x"].shape[0]
+    rtol = 4 * rounds * np.finfo(np.float32).eps
     for policy, n_atk in [(Policy.BEV, 1), (Policy.CI, 0), (Policy.EF, 2)]:
         floa = _tiny_floa(dim, policy, n_atk, noise=0.0)
         tr = FLTrainer(loss_fn=loss, floa=floa, alpha=0.05)
-        rounds = batches["x"].shape[0]
         _, logs = tr.run(dict(params), _Replay(batches), rounds,
                          jax.random.PRNGKey(9), eval_every=1)
         res = SweepEngine(loss, SweepSpec.build(
             [ScenarioCase("x", floa, 0.05, seed=9)])).run(params, batches)
         np.testing.assert_allclose(
             np.asarray([l.loss for l in logs]), res.loss[0],
-            rtol=1e-6, atol=1e-7)
+            rtol=rtol, atol=0)
 
 
 def test_sweep_honors_power_accounting_dim():
