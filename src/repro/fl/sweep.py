@@ -71,6 +71,7 @@ varies per scenario.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import warnings
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -124,6 +125,48 @@ _FOLD_MARKOV = 4    # Gauss-Markov fading innovation
 _FOLD_PART = 5      # K-of-U participation mask
 _FOLD_H_INIT = 7    # folded on the lane BASE key: stationary h_0 state
 
+# Layer scopes of the compiled round.  They are HLO op_name metadata only
+# (the compiled program is the same instruction for instruction), and a
+# profiler trace carries them as each device op's `tf_op` path, so a layer's
+# device time is the time of the ops whose path names its scope.  Every
+# round builder uses the same five names.
+SCOPE_GRADS = "sweep_grads"       # per-worker gradients -> the [S, U, D] slab
+SCOPE_COMBINE = "sweep_combine"   # stats, channel/Markov/participation draws,
+#                                   coefficients, noise, OTA combine, jamming,
+#                                   cohort direction, analog lanes' PS update
+SCOPE_SCREEN = "sweep_screen"     # digital flip, defense kernels or the
+#                                   lax.switch selector, screened PS update
+SCOPE_LOSS = "sweep_loss"         # post-update loss forward, aggregate norm
+SCOPE_EVAL = "sweep_eval"         # eval_fn on its schedule
+LAYER_SCOPES = (SCOPE_GRADS, SCOPE_COMBINE, SCOPE_SCREEN, SCOPE_LOSS,
+                SCOPE_EVAL)
+
+# jax.monitoring scalar recorded once per `SweepEngine.run`: the bytes of
+# the device arrays staged from host arrays in that run, over all chunks.
+H2D_BYTES = "/repro/sweep/h2d_bytes"
+
+
+def _layer(name: str):
+    """The named scope of one layer of the round (see SCOPE_*)."""
+    return jax.named_scope(name)
+
+
+# A host span (TraceMe) on the profiler's clock.  With no profiler running
+# it costs one check; its keyword arguments are recorded only when one is.
+_span = jax.profiler.TraceAnnotation
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """fn under a stable name: jit names the program after it
+    (`jit_sweep_run`), so a trace tells the sweep's ops from helper jits'.
+    The signature (and so the program's parameter names) stays fn's."""
+    @functools.wraps(fn)
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
 
 @dataclasses.dataclass(frozen=True)
 class ScenarioCase:
@@ -160,12 +203,13 @@ class SweepSpec:
     @classmethod
     def build(cls, cases: Sequence) -> "SweepSpec":
         """Accepts ScenarioCase instances or (name, floa, alpha[, seed]) tuples."""
-        out = []
-        for c in cases:
-            if not isinstance(c, ScenarioCase):
-                c = ScenarioCase(*c)
-            out.append(c)
-        return cls(cases=tuple(out))
+        with _span("sweep.spec"):
+            out = []
+            for c in cases:
+                if not isinstance(c, ScenarioCase):
+                    c = ScenarioCase(*c)
+                out.append(c)
+            return cls(cases=tuple(out))
 
     def __post_init__(self):
         assert self.cases, "empty sweep"
@@ -718,82 +762,85 @@ class SweepEngine:
         explicitly build the equivalent plan (DeprecationWarning); mixing
         them with plan= raises.
         """
-        legacy = {k: v for k, v in dict(
-            flat_state=flat_state, mesh=mesh, strict_numerics=strict_numerics,
-            grouped_dispatch=grouped_dispatch, chunk_rounds=chunk_rounds,
-            async_staging=async_staging).items() if v is not _UNSET}
-        if legacy:
-            if plan is not None:
-                raise ValueError(
-                    f"pass the execution strategy as plan=ExecutionPlan(...) "
-                    f"OR as the legacy per-knob kwargs, not both (got plan "
-                    f"and {sorted(legacy)})")
-            warnings.warn(
-                "SweepEngine's per-knob execution kwargs (flat_state, mesh, "
-                "strict_numerics, grouped_dispatch, chunk_rounds, "
-                "async_staging) are deprecated; pass "
-                "plan=ExecutionPlan(...) instead",
-                DeprecationWarning, stacklevel=2)
-            plan = ExecutionPlan(**legacy)
-        elif plan is None:
-            plan = ExecutionPlan()
-        self.plan = plan
-        self.loss_fn = loss_fn
-        self.spec = spec
-        self.eval_fn = eval_fn
-        self.eval_every = eval_every
-        # Legacy attribute surface: downstream code (tests, benchmarks)
-        # reads the knobs off the engine; keep them as plain mirrors of the
-        # plan.
-        self.flat_state = plan.flat_state
-        self.mesh = plan.mesh
-        self.strict_numerics = plan.strict_numerics
-        self.grouped_dispatch = plan.grouped_dispatch
-        self.chunk_rounds = plan.chunk_rounds
-        self.async_staging = plan.async_staging
-        self.checkpoint_dir = plan.checkpoint_dir
-        self.checkpoint_every_chunks = plan.checkpoint_every_chunks
-        self._num = len(spec)
-        self._u = spec.num_workers
-        self._sp = spec.stacked_params()
-        shards = plan.data_shards
-        self._ws = (_WorkerShards(self._u, plan.worker_shards)
-                    if plan.worker_sharded else None)
-        # Model-axis sharding arithmetic is built lazily in _build: the
-        # flat parameter count D only arrives with the params template.
-        self._ms = None
-        # Grouped dispatch only matters when a screening defense shares the
-        # grid with other families; pure-FLOA sweeps keep the untouched
-        # (unpermuted) fused path regardless of the flag.
-        self._groups = (SC.build_lane_groups(spec.lane_codes, shards)
-                        if plan.grouped_dispatch and spec.any_digital
-                        else None)
-        if self._groups is not None:
-            self._pad = self._groups.exec_lanes - self._num
-            if self._groups.num_ghosts > self._num:
-                # Per-group padding to the device count blew the executed
-                # lane axis up past 2x: every ghost lane runs (discarded)
-                # grads/loss/eval each round, so grouped dispatch can LOSE
-                # to the switch path here — say so instead of silently
-                # inverting the default's advantage.
+        with _span("sweep.init"):
+            legacy = {k: v for k, v in dict(
+                flat_state=flat_state, mesh=mesh, strict_numerics=strict_numerics,
+                grouped_dispatch=grouped_dispatch, chunk_rounds=chunk_rounds,
+                async_staging=async_staging).items() if v is not _UNSET}
+            if legacy:
+                if plan is not None:
+                    raise ValueError(
+                        f"pass the execution strategy as plan=ExecutionPlan(...) "
+                        f"OR as the legacy per-knob kwargs, not both (got plan "
+                        f"and {sorted(legacy)})")
                 warnings.warn(
-                    f"grouped dispatch executes {self._groups.exec_lanes} "
-                    f"lanes for {self._num} scenarios ({self._groups.num_ghosts}"
-                    f" ghosts: {len(self._groups.codes)} defense-code groups "
-                    f"each padded to a multiple of {shards} devices); with "
-                    f"groups this small relative to the mesh, "
-                    f"grouped_dispatch=False may be faster")
-            self._sp_run = SC.permute_lanes(self._sp, self._groups.perm)
-        else:
-            self._pad = -self._num % shards
-            self._sp_run = SC.pad_lanes(self._sp, self._num + self._pad)
-        # The compiled program is built lazily on the first run: the flat
-        # path needs the params template (leaf shapes/dtypes) to cache its
-        # row unflatten, and that only arrives with params0.
-        self._run_jit = None
-        self._chunk_jit = None
-        self._finalize_jit = None
-        self._template = None
+                    "SweepEngine's per-knob execution kwargs (flat_state, mesh, "
+                    "strict_numerics, grouped_dispatch, chunk_rounds, "
+                    "async_staging) are deprecated; pass "
+                    "plan=ExecutionPlan(...) instead",
+                    DeprecationWarning, stacklevel=2)
+                plan = ExecutionPlan(**legacy)
+            elif plan is None:
+                plan = ExecutionPlan()
+            self.plan = plan
+            self.loss_fn = loss_fn
+            self.spec = spec
+            self.eval_fn = eval_fn
+            self.eval_every = eval_every
+            # Legacy attribute surface: downstream code (tests, benchmarks)
+            # reads the knobs off the engine; keep them as plain mirrors of the
+            # plan.
+            self.flat_state = plan.flat_state
+            self.mesh = plan.mesh
+            self.strict_numerics = plan.strict_numerics
+            self.grouped_dispatch = plan.grouped_dispatch
+            self.chunk_rounds = plan.chunk_rounds
+            self.async_staging = plan.async_staging
+            self.checkpoint_dir = plan.checkpoint_dir
+            self.checkpoint_every_chunks = plan.checkpoint_every_chunks
+            self._num = len(spec)
+            self._u = spec.num_workers
+            self._sp = spec.stacked_params()
+            shards = plan.data_shards
+            self._ws = (_WorkerShards(self._u, plan.worker_shards)
+                        if plan.worker_sharded else None)
+            # Model-axis sharding arithmetic is built lazily in _build: the
+            # flat parameter count D only arrives with the params template.
+            self._ms = None
+            # Grouped dispatch only matters when a screening defense shares the
+            # grid with other families; pure-FLOA sweeps keep the untouched
+            # (unpermuted) fused path regardless of the flag.
+            self._groups = (SC.build_lane_groups(spec.lane_codes, shards)
+                            if plan.grouped_dispatch and spec.any_digital
+                            else None)
+            if self._groups is not None:
+                self._pad = self._groups.exec_lanes - self._num
+                if self._groups.num_ghosts > self._num:
+                    # Per-group padding to the device count blew the executed
+                    # lane axis up past 2x: every ghost lane runs (discarded)
+                    # grads/loss/eval each round, so grouped dispatch can LOSE
+                    # to the switch path here — say so instead of silently
+                    # inverting the default's advantage.
+                    warnings.warn(
+                        f"grouped dispatch executes {self._groups.exec_lanes} "
+                        f"lanes for {self._num} scenarios ({self._groups.num_ghosts}"
+                        f" ghosts: {len(self._groups.codes)} defense-code groups "
+                        f"each padded to a multiple of {shards} devices); with "
+                        f"groups this small relative to the mesh, "
+                        f"grouped_dispatch=False may be faster")
+                self._sp_run = SC.permute_lanes(self._sp, self._groups.perm)
+            else:
+                self._pad = -self._num % shards
+                self._sp_run = SC.pad_lanes(self._sp, self._num + self._pad)
+            # The compiled program is built lazily on the first run: the flat
+            # path needs the params template (leaf shapes/dtypes) to cache its
+            # row unflatten, and that only arrives with params0.
+            self._run_jit = None
+            self._chunk_jit = None
+            self._finalize_jit = None
+            self._template = None
+            self._runs = 0          # the run index each sweep.run span carries
+            self._h2d_bytes = 0     # this run's H2D_BYTES so far
 
     # ------------------------------------------------------------ builders
 
@@ -1067,19 +1114,21 @@ class SweepEngine:
             unconditionally so every mesh shard agrees on the program."""
             if eval_lane is None:
                 return {}
-            rows = state if eval_prep is None else eval_prep(state)
+            with _layer(SCOPE_EVAL):
+                rows = state if eval_prep is None else eval_prep(state)
 
-            def as_f32(s_):
-                return jax.tree_util.tree_map(
-                    lambda x: x.astype(jnp.float32), jax.vmap(eval_lane)(s_))
+                def as_f32(s_):
+                    return jax.tree_util.tree_map(
+                        lambda x: x.astype(jnp.float32),
+                        jax.vmap(eval_lane)(s_))
 
-            shapes = jax.eval_shape(as_f32, rows)
-            blank = jax.tree_util.tree_map(
-                lambda s: jnp.full(s.shape, jnp.nan, s.dtype), shapes)
-            due = (t == rounds - 1)
-            if eval_every > 0:
-                due = due | (t % eval_every == 0)
-            return jax.lax.cond(due, as_f32, lambda _: blank, rows)
+                shapes = jax.eval_shape(as_f32, rows)
+                blank = jax.tree_util.tree_map(
+                    lambda s: jnp.full(s.shape, jnp.nan, s.dtype), shapes)
+                due = (t == rounds - 1)
+                if eval_every > 0:
+                    due = due | (t % eval_every == 0)
+                return jax.lax.cond(due, as_f32, lambda _: blank, rows)
 
         def scan_chunk(state, keys, t0, rounds_total, batches, sp):
             def body(carry, batch):
@@ -1158,47 +1207,54 @@ class SweepEngine:
 
         def one_round(state, batch, sub_s, sp: SC.ScenarioParams):
             params_s = state[0] if any_markov else state
-            grads = jax.vmap(
-                lambda p: per_worker_grads(loss_fn, p, batch, u)[0]
-            )(params_s)
-            flat, unflatten = flatten_worker_grads(grads, batch_dims=2)
-            if strict:
-                flat = jax.lax.optimization_barrier(flat)
+            with _layer(SCOPE_GRADS):
+                grads = jax.vmap(
+                    lambda p: per_worker_grads(loss_fn, p, batch, u)[0]
+                )(params_s)
+                flat, unflatten = flatten_worker_grads(grads, batch_dims=2)
+                if strict:
+                    flat = jax.lax.optimization_barrier(flat)
             num = flat.shape[0]
-            if any_markov:
-                h_new, h_abs_all = markov_update(state[1], sub_s, sp)
-            else:
-                h_new, h_abs_all = None, None
-            part_all = part_draw(sub_s, sp) if any_partial else None
+            with _layer(SCOPE_COMBINE):
+                if any_markov:
+                    h_new, h_abs_all = markov_update(state[1], sub_s, sp)
+                else:
+                    h_new, h_abs_all = None, None
+                part_all = part_draw(sub_s, sp) if any_partial else None
             parts = []
             for code, start, end in local_slices:
-                sl = slice(start, end)
-                fg = flat[sl]
-                spg = jax.tree_util.tree_map(lambda x: x[sl], sp)
-                part_g = None if part_all is None else part_all[sl]
-                if code == SC._FLOA_CODE:
-                    if strict:
-                        gbar_i, eps2_i = jax.vmap(
-                            lambda g: S.flat_scalar_stats(g, sizes))(fg)
+                analog = code == SC._FLOA_CODE
+                with _layer(SCOPE_COMBINE if analog else SCOPE_SCREEN):
+                    sl = slice(start, end)
+                    fg = flat[sl]
+                    spg = jax.tree_util.tree_map(lambda x: x[sl], sp)
+                    part_g = None if part_all is None else part_all[sl]
+                    if analog:
+                        if strict:
+                            gbar_i, eps2_i = jax.vmap(
+                                lambda g: S.flat_scalar_stats(g, sizes))(fg)
+                        else:
+                            grads_g = jax.tree_util.tree_map(
+                                lambda x: x[sl], grads)
+                            gbar_i, eps2_i = jax.vmap(
+                                S.per_worker_scalar_stats)(grads_g)
+                        _, gagg_g = analog_step(
+                            None, fg, sub_s[sl], spg, gbar_i, eps2_i,
+                            part=part_g,
+                            h_abs=(None if h_abs_all is None
+                                   else h_abs_all[sl]))
                     else:
-                        grads_g = jax.tree_util.tree_map(
-                            lambda x: x[sl], grads)
-                        gbar_i, eps2_i = jax.vmap(
-                            S.per_worker_scalar_stats)(grads_g)
-                    _, gagg_g = analog_step(
-                        None, fg, sub_s[sl], spg, gbar_i, eps2_i,
-                        part=part_g,
-                        h_abs=None if h_abs_all is None else h_abs_all[sl])
-                else:
-                    flipped = _digital_flip(fg, spg)
-                    if any_partial:
-                        gagg_g = kernels[code](flipped, spg.def_trim,
-                                               spg.def_f, spg.def_multi,
-                                               part_g)
-                    else:
-                        gagg_g = kernels[code](flipped, spg.def_trim,
-                                               spg.def_f, spg.def_multi)
+                        flipped = _digital_flip(fg, spg)
+                        if any_partial:
+                            gagg_g = kernels[code](flipped, spg.def_trim,
+                                                   spg.def_f, spg.def_multi,
+                                                   part_g)
+                        else:
+                            gagg_g = kernels[code](flipped, spg.def_trim,
+                                                   spg.def_f, spg.def_multi)
                 parts.append(gagg_g)
+            # The concatenation and the one PS update of every group's
+            # lanes stay unscoped: they belong to no single layer.
             gagg_flat = jnp.concatenate(parts, axis=0)
 
             gagg = unflatten(gagg_flat)
@@ -1206,8 +1262,9 @@ class SweepEngine:
                 lambda p, g: p - (sp.alpha.reshape((num,) + (1,) * (p.ndim - 1))
                                   * g).astype(p.dtype),
                 params_s, gagg)
-            gn = jnp.sqrt(jnp.sum(jnp.square(gagg_flat), axis=-1))
-            loss = jax.vmap(lambda p: loss_fn(p, batch))(new_params)
+            with _layer(SCOPE_LOSS):
+                gn = jnp.sqrt(jnp.sum(jnp.square(gagg_flat), axis=-1))
+                loss = jax.vmap(lambda p: loss_fn(p, batch))(new_params)
             new_state = (new_params, h_new) if any_markov else new_params
             return new_state, loss, gn
 
@@ -1256,110 +1313,117 @@ class SweepEngine:
         def flat_loss(w_row, batch):
             return loss_fn(unflatten_row(w_row), batch)
 
+        def group_step(code, sl, w, grads, sub_s, sp, part_all, h_abs_all):
+            """One group's (w_new, gagg) on its lane slice `sl`: the analog
+            leg (fused PS update), or the family's screen and its update."""
+            wg, fg = w[sl], grads[sl]
+            spg = jax.tree_util.tree_map(lambda x: x[sl], sp)
+            part_g = None if part_all is None else part_all[sl]
+            if code == SC._FLOA_CODE:
+                if strict:
+                    gbar_i, eps2_i = jax.vmap(
+                        lambda g: S.flat_scalar_stats(g, sizes))(fg)
+                elif ms_run is not None:
+                    # Shard-local partial sums -> two scalar psums per
+                    # worker over "model" (ghost columns contribute exactly
+                    # 0.0); the shared epilogue recovers the full-row
+                    # stats.  See standardize.flat_partial_stats for the fp
+                    # contract (rtol vs the single-sum path).
+                    s1, s2 = S.flat_partial_stats(fg)
+                    s1 = jax.lax.psum(s1, "model")
+                    s2 = jax.lax.psum(s2, "model")
+                    gbar_i, eps2_i = S.stats_from_partials(s1, s2, ms.d)
+                    if ws_run is not None:
+                        gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
+                else:
+                    gbar_i, eps2_i = jax.vmap(
+                        lambda g: S.flat_scalar_stats(g))(fg)
+                    if ws_run is not None:
+                        gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
+                return analog_step(
+                    wg, fg, sub_s[sl], spg, gbar_i, eps2_i, part=part_g,
+                    h_abs=None if h_abs_all is None else h_abs_all[sl])
+            fg_full = ws.gather_slab(fg) if ws_run is not None else fg
+            # Column-wise screens (mean/median/trimmed-mean) are
+            # per-coordinate over the worker axis, so they run on the local
+            # column block as-is; row-geometry screens (Krum family,
+            # geometric median) score whole rows by pairwise distance and
+            # need the full columns gathered.
+            row_geo = ms_run is not None and code not in DEF.COLUMNWISE_CODES
+            if row_geo:
+                fg_full = ms.gather_cols(fg_full)
+            flipped = _digital_flip(fg_full, spg)
+            if any_partial:
+                gagg_g = kernels[code](flipped, spg.def_trim, spg.def_f,
+                                       spg.def_multi, part_g)
+            else:
+                gagg_g = kernels[code](flipped, spg.def_trim, spg.def_f,
+                                       spg.def_multi)
+            if row_geo:
+                gagg_g = ms.local_cols(gagg_g)
+            elif ms_run is not None:
+                # Column-wise outputs on all-zero ghost columns are zero in
+                # exact arithmetic; the mask makes the invariant
+                # unconditional (bitwise identity on real columns).
+                gagg_g = jnp.where(ms.col_mask(), gagg_g, 0.0)
+            return wg - spg.alpha[:, None] * gagg_g, gagg_g
+
         def one_round(state, batch, sub_s, sp: SC.ScenarioParams):
             w = state[0] if any_markov else state
-            if ms is not None:
-                # Gradients always come off the FULL real-D rows: the
-                # gather reconstructs exactly the unsharded row values, so
-                # the per-worker grad trace is the unsharded engine's.
-                # Strict mode then keeps everything full-width (re-slicing
-                # only the carry at the end — the bitwise contract);
-                # the default re-slices the slab to this shard's columns.
-                w = ms.gather_cols(w)
-            if ws is None:
-                grads = jax.vmap(
-                    lambda wr: per_worker_grads(flat_loss, wr, batch, u)[0]
-                )(w)  # [S, U, D]
-            else:
-                lb = ws.local_batch(batch)
-                grads = jax.vmap(
-                    lambda wr: per_worker_grads(flat_loss, wr, lb,
-                                                ws.u_loc)[0]
-                )(w)  # [S, u_loc, D]
-                if strict:
-                    grads = ws.gather_slab(grads)
-            if ms_run is not None:
-                grads = ms.local_cols(grads)
-                w = state[0] if any_markov else state  # back to local cols
-            if strict and has_analog:
-                grads = jax.lax.optimization_barrier(grads)
-            if any_markov:
-                h_new, h_abs_all = markov_update(state[1], sub_s, sp)
-            else:
-                h_new, h_abs_all = None, None
-            part_all = part_draw(sub_s, sp) if any_partial else None
+            with _layer(SCOPE_GRADS):
+                if ms is not None:
+                    # Gradients always come off the FULL real-D rows: the
+                    # gather reconstructs exactly the unsharded row values,
+                    # so the per-worker grad trace is the unsharded
+                    # engine's.  Strict mode then keeps everything
+                    # full-width (re-slicing only the carry at the end —
+                    # the bitwise contract); the default re-slices the slab
+                    # to this shard's columns.
+                    w = ms.gather_cols(w)
+                if ws is None:
+                    grads = jax.vmap(
+                        lambda wr: per_worker_grads(flat_loss, wr, batch, u)[0]
+                    )(w)  # [S, U, D]
+                else:
+                    lb = ws.local_batch(batch)
+                    grads = jax.vmap(
+                        lambda wr: per_worker_grads(flat_loss, wr, lb,
+                                                    ws.u_loc)[0]
+                    )(w)  # [S, u_loc, D]
+                    if strict:
+                        grads = ws.gather_slab(grads)
+                if ms_run is not None:
+                    grads = ms.local_cols(grads)
+                    w = state[0] if any_markov else state  # back to local cols
+                if strict and has_analog:
+                    grads = jax.lax.optimization_barrier(grads)
+            with _layer(SCOPE_COMBINE):
+                if any_markov:
+                    h_new, h_abs_all = markov_update(state[1], sub_s, sp)
+                else:
+                    h_new, h_abs_all = None, None
+                part_all = part_draw(sub_s, sp) if any_partial else None
             w_parts, g_parts = [], []
             for code, start, end in local_slices:
-                sl = slice(start, end)
-                wg, fg = w[sl], grads[sl]
-                spg = jax.tree_util.tree_map(lambda x: x[sl], sp)
-                part_g = None if part_all is None else part_all[sl]
-                if code == SC._FLOA_CODE:
-                    if strict:
-                        gbar_i, eps2_i = jax.vmap(
-                            lambda g: S.flat_scalar_stats(g, sizes))(fg)
-                    elif ms_run is not None:
-                        # Shard-local partial sums -> two scalar psums per
-                        # worker over "model" (ghost columns contribute
-                        # exactly 0.0); the shared epilogue recovers the
-                        # full-row stats.  See standardize.flat_partial_stats
-                        # for the fp contract (rtol vs the single-sum path).
-                        s1, s2 = S.flat_partial_stats(fg)
-                        s1 = jax.lax.psum(s1, "model")
-                        s2 = jax.lax.psum(s2, "model")
-                        gbar_i, eps2_i = S.stats_from_partials(s1, s2, ms.d)
-                        if ws_run is not None:
-                            gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
-                    else:
-                        gbar_i, eps2_i = jax.vmap(
-                            lambda g: S.flat_scalar_stats(g))(fg)
-                        if ws_run is not None:
-                            gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
-                    w_new_g, gagg_g = analog_step(
-                        wg, fg, sub_s[sl], spg, gbar_i, eps2_i,
-                        part=part_g,
-                        h_abs=None if h_abs_all is None else h_abs_all[sl])
-                else:
-                    fg_full = (ws.gather_slab(fg) if ws_run is not None
-                               else fg)
-                    # Column-wise screens (mean/median/trimmed-mean) are
-                    # per-coordinate over the worker axis, so they run on
-                    # the local column block as-is; row-geometry screens
-                    # (Krum family, geometric median) score whole rows by
-                    # pairwise distance and need the full columns gathered.
-                    row_geo = (ms_run is not None
-                               and code not in DEF.COLUMNWISE_CODES)
-                    if row_geo:
-                        fg_full = ms.gather_cols(fg_full)
-                    flipped = _digital_flip(fg_full, spg)
-                    if any_partial:
-                        gagg_g = kernels[code](flipped, spg.def_trim,
-                                               spg.def_f, spg.def_multi,
-                                               part_g)
-                    else:
-                        gagg_g = kernels[code](flipped, spg.def_trim,
-                                               spg.def_f, spg.def_multi)
-                    if row_geo:
-                        gagg_g = ms.local_cols(gagg_g)
-                    elif ms_run is not None:
-                        # Column-wise outputs on all-zero ghost columns are
-                        # zero in exact arithmetic; the mask makes the
-                        # invariant unconditional (bitwise identity on real
-                        # columns).
-                        gagg_g = jnp.where(ms.col_mask(), gagg_g, 0.0)
-                    w_new_g = wg - spg.alpha[:, None] * gagg_g
+                analog = code == SC._FLOA_CODE
+                with _layer(SCOPE_COMBINE if analog else SCOPE_SCREEN):
+                    w_new_g, gagg_g = group_step(
+                        code, slice(start, end), w, grads, sub_s, sp,
+                        part_all, h_abs_all)
                 w_parts.append(w_new_g)
                 g_parts.append(gagg_g)
+            # The group concatenation stays unscoped: no single layer's.
             w_new = jnp.concatenate(w_parts, axis=0)
             gagg = jnp.concatenate(g_parts, axis=0)
-            if ms_run is not None:
-                gn = jnp.sqrt(jax.lax.psum(
-                    jnp.sum(jnp.square(gagg), axis=-1), "model"))
-                loss = jax.vmap(lambda wr: flat_loss(wr, batch))(
-                    ms.gather_cols(w_new))
-            else:
-                gn = jnp.sqrt(jnp.sum(jnp.square(gagg), axis=-1))
-                loss = jax.vmap(lambda wr: flat_loss(wr, batch))(w_new)
+            with _layer(SCOPE_LOSS):
+                if ms_run is not None:
+                    gn = jnp.sqrt(jax.lax.psum(
+                        jnp.sum(jnp.square(gagg), axis=-1), "model"))
+                    loss = jax.vmap(lambda wr: flat_loss(wr, batch))(
+                        ms.gather_cols(w_new))
+                else:
+                    gn = jnp.sqrt(jnp.sum(jnp.square(gagg), axis=-1))
+                    loss = jax.vmap(lambda wr: flat_loss(wr, batch))(w_new)
             if ms is not None and strict:
                 w_new = ms.local_cols(w_new)
             new_state = (w_new, h_new) if any_markov else w_new
@@ -1394,55 +1458,73 @@ class SweepEngine:
         def one_round(state, batch, sub_s, sp: SC.ScenarioParams):
             params_s = state[0] if any_markov else state
             # 1. per-worker local SGD gradients, per scenario: leaves [S, U, ...]
-            grads = jax.vmap(
-                lambda p: per_worker_grads(loss_fn, p, batch, u)[0]
-            )(params_s)
-            if any_markov:
-                h_new, h_abs = markov_update(state[1], sub_s, sp)
-            else:
-                h_new, h_abs = None, None
-            part = part_draw(sub_s, sp) if any_partial else None
+            with _layer(SCOPE_GRADS):
+                grads = jax.vmap(
+                    lambda p: per_worker_grads(loss_fn, p, batch, u)[0]
+                )(params_s)
+            with _layer(SCOPE_COMBINE):
+                if any_markov:
+                    h_new, h_abs = markov_update(state[1], sub_s, sp)
+                else:
+                    h_new, h_abs = None, None
+                part = part_draw(sub_s, sp) if any_partial else None
 
             if all_digital:
                 # No analog leg to trace (mirrors the flat-state path, so
                 # strict_numerics stays bitwise across representations).
-                flat, unflatten = flatten_worker_grads(grads, batch_dims=2)
+                with _layer(SCOPE_GRADS):
+                    flat, unflatten = flatten_worker_grads(grads,
+                                                           batch_dims=2)
                 num = flat.shape[0]
-                gagg_flat = digital_select(None, flat, sp, part)
+                with _layer(SCOPE_SCREEN):
+                    gagg_flat = digital_select(None, flat, sp, part)
             else:
                 # 2. scalar-stat standardization handshake.
                 if strict:
                     # Barrier first: stats reduce from the materialized slab
                     # (needed by the combine anyway), bit-matching the strict
                     # flat-state path.
-                    flat, unflatten = flatten_worker_grads(grads, batch_dims=2)
-                    flat = jax.lax.optimization_barrier(flat)
-                    gbar_i, eps2_i = jax.vmap(
-                        lambda g: S.flat_scalar_stats(g, sizes))(flat)
+                    with _layer(SCOPE_GRADS):
+                        flat, unflatten = flatten_worker_grads(
+                            grads, batch_dims=2)
+                        flat = jax.lax.optimization_barrier(flat)
+                    with _layer(SCOPE_COMBINE):
+                        gbar_i, eps2_i = jax.vmap(
+                            lambda g: S.flat_scalar_stats(g, sizes))(flat)
                 else:
-                    gbar_i, eps2_i = jax.vmap(S.per_worker_scalar_stats)(grads)
-                    flat, unflatten = flatten_worker_grads(grads, batch_dims=2)
+                    with _layer(SCOPE_COMBINE):
+                        gbar_i, eps2_i = jax.vmap(
+                            S.per_worker_scalar_stats)(grads)
+                    with _layer(SCOPE_GRADS):
+                        flat, unflatten = flatten_worker_grads(
+                            grads, batch_dims=2)
                 num = flat.shape[0]
                 # 3+4. channel draw + coefficients + OTA combine (+ jam +
                 # directional cohort), the shared analog leg; wg=None keeps
                 # the two-step route the tree update needs.
-                _, gagg_flat = analog_step(None, flat, sub_s, sp,
-                                           gbar_i, eps2_i,
-                                           part=part, h_abs=h_abs)
+                with _layer(SCOPE_COMBINE):
+                    _, gagg_flat = analog_step(None, flat, sub_s, sp,
+                                               gbar_i, eps2_i,
+                                               part=part, h_abs=h_abs)
                 if digital_select is not None:
                     # Defense lanes override the analog combine with their
                     # screening defense on the same (already materialized) slab.
-                    gagg_flat = digital_select(gagg_flat, flat, sp, part)
+                    with _layer(SCOPE_SCREEN):
+                        gagg_flat = digital_select(gagg_flat, flat, sp, part)
 
-            # 5. PS update w <- w - alpha * gagg (per-scenario alpha).
-            gagg = unflatten(gagg_flat)
-            new_params = jax.tree_util.tree_map(
-                lambda p, g: p - (sp.alpha.reshape((num,) + (1,) * (p.ndim - 1))
-                                  * g).astype(p.dtype),
-                params_s, gagg)
+            # 5. PS update w <- w - alpha * gagg (per-scenario alpha), in
+            # the layer whose aggregate it applies.
+            with _layer(SCOPE_SCREEN if digital_select is not None
+                        else SCOPE_COMBINE):
+                gagg = unflatten(gagg_flat)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, g: p - (sp.alpha.reshape(
+                        (num,) + (1,) * (p.ndim - 1)) * g).astype(p.dtype),
+                    params_s, gagg)
 
-            gn = jnp.sqrt(jnp.sum(jnp.square(gagg_flat), axis=-1))
-            loss = jax.vmap(lambda p: loss_fn(p, batch))(new_params)
+            with _layer(SCOPE_LOSS):
+                gn = jnp.sqrt(jnp.sum(jnp.square(gagg_flat), axis=-1))
+                loss = jax.vmap(lambda p: loss_fn(p, batch))(new_params)
             new_state = (new_params, h_new) if any_markov else new_params
             return new_state, loss, gn
 
@@ -1505,41 +1587,45 @@ class SweepEngine:
             # engine's.  `w` below is the width the round's update math
             # runs at: full columns in strict mode (re-slicing only the
             # carry — the bitwise contract), local columns otherwise.
-            w_full = ms.gather_cols(w_loc) if ms is not None else w_loc
+            with _layer(SCOPE_GRADS):
+                w_full = ms.gather_cols(w_loc) if ms is not None else w_loc
+                # 1. per-worker gradients, already flat: [S, U, D] (the
+                # local [S, u_loc, D] slice under worker sharding).
+                if ws is None:
+                    grads = jax.vmap(
+                        lambda wr: per_worker_grads(flat_loss, wr, batch, u)[0]
+                    )(w_full)
+                else:
+                    lb = ws.local_batch(batch)
+                    grads = jax.vmap(
+                        lambda wr: per_worker_grads(flat_loss, wr, lb,
+                                                    ws.u_loc)[0]
+                    )(w_full)
+                    if strict or all_digital:
+                        grads = ws.gather_slab(grads)
             w = w_loc if ms_run is not None else w_full
-            # 1. per-worker gradients, already flat: [S, U, D] (the local
-            # [S, u_loc, D] slice under worker sharding).
-            if ws is None:
-                grads = jax.vmap(
-                    lambda wr: per_worker_grads(flat_loss, wr, batch, u)[0]
-                )(w_full)
-            else:
-                lb = ws.local_batch(batch)
-                grads = jax.vmap(
-                    lambda wr: per_worker_grads(flat_loss, wr, lb,
-                                                ws.u_loc)[0]
-                )(w_full)
-                if strict or all_digital:
-                    grads = ws.gather_slab(grads)
-            if any_markov:
-                h_new, h_abs = markov_update(state[1], sub_s, sp)
-            else:
-                h_new, h_abs = None, None
-            part = part_draw(sub_s, sp) if any_partial else None
+            with _layer(SCOPE_COMBINE):
+                if any_markov:
+                    h_new, h_abs = markov_update(state[1], sub_s, sp)
+                else:
+                    h_new, h_abs = None, None
+                part = part_draw(sub_s, sp) if any_partial else None
 
             def outputs(w_new, gagg):
                 """gn / loss / carry epilogue, shared by every leg.  With
                 local columns the squared norm psums over "model" and the
                 loss reads gathered rows; strict model sharding computed
                 full-width and re-slices only the carry."""
-                if ms_run is not None:
-                    gn = jnp.sqrt(jax.lax.psum(
-                        jnp.sum(jnp.square(gagg), axis=-1), "model"))
-                    loss = jax.vmap(lambda wr: flat_loss(wr, batch))(
-                        ms.gather_cols(w_new))
-                else:
-                    gn = jnp.sqrt(jnp.sum(jnp.square(gagg), axis=-1))
-                    loss = jax.vmap(lambda wr: flat_loss(wr, batch))(w_new)
+                with _layer(SCOPE_LOSS):
+                    if ms_run is not None:
+                        gn = jnp.sqrt(jax.lax.psum(
+                            jnp.sum(jnp.square(gagg), axis=-1), "model"))
+                        loss = jax.vmap(lambda wr: flat_loss(wr, batch))(
+                            ms.gather_cols(w_new))
+                    else:
+                        gn = jnp.sqrt(jnp.sum(jnp.square(gagg), axis=-1))
+                        loss = jax.vmap(lambda wr: flat_loss(wr, batch))(
+                            w_new)
                 if ms is not None and strict:
                     w_new = ms.local_cols(w_new)
                 new_state = (w_new, h_new) if any_markov else w_new
@@ -1552,14 +1638,16 @@ class SweepEngine:
             # full columns (grads were never column-sliced on this leg);
             # its output re-slices local, ghost columns exact zeros.
             if all_digital:
-                gagg = digital_select(None, grads, sp, part)
-                if ms_run is not None:
-                    gagg = ms.local_cols(gagg)
-                w_new = w - sp.alpha[:, None] * gagg
+                with _layer(SCOPE_SCREEN):
+                    gagg = digital_select(None, grads, sp, part)
+                    if ms_run is not None:
+                        gagg = ms.local_cols(gagg)
+                    w_new = w - sp.alpha[:, None] * gagg
                 return outputs(w_new, gagg)
 
             if ms_run is not None:
-                grads = ms.local_cols(grads)
+                with _layer(SCOPE_GRADS):
+                    grads = ms.local_cols(grads)
 
             # 2. standardization handshake.  strict_numerics pins the fp
             # reduction tree to the tree-state path's (materialization
@@ -1568,36 +1656,42 @@ class SweepEngine:
             # into the gradient producer — one less pass over the slab, at
             # the price of ulp-level stat differences.
             if strict:
-                grads = jax.lax.optimization_barrier(grads)
-                gbar_i, eps2_i = jax.vmap(
-                    lambda g: S.flat_scalar_stats(g, sizes))(grads)
-            elif ms_run is not None:
-                # Shard-local partial sums -> two scalar psums per worker
-                # over "model" (ghost columns contribute exactly 0.0); see
-                # standardize.flat_partial_stats for the fp contract.
-                s1, s2 = S.flat_partial_stats(grads)
-                s1 = jax.lax.psum(s1, "model")
-                s2 = jax.lax.psum(s2, "model")
-                gbar_i, eps2_i = S.stats_from_partials(s1, s2, ms.d)
-                if ws_run is not None:
-                    gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
-            else:
-                gbar_i, eps2_i = jax.vmap(
-                    lambda g: S.flat_scalar_stats(g))(grads)
-                if ws_run is not None:
-                    # Local per-worker scalars -> full [S, U]: the global
-                    # mean then reduces the same vector the unsharded
-                    # engine reduces (bitwise-equal stats).
-                    gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
+                with _layer(SCOPE_GRADS):
+                    grads = jax.lax.optimization_barrier(grads)
+            with _layer(SCOPE_COMBINE):
+                if strict:
+                    gbar_i, eps2_i = jax.vmap(
+                        lambda g: S.flat_scalar_stats(g, sizes))(grads)
+                elif ms_run is not None:
+                    # Shard-local partial sums -> two scalar psums per
+                    # worker over "model" (ghost columns contribute exactly
+                    # 0.0); see standardize.flat_partial_stats for the fp
+                    # contract.
+                    s1, s2 = S.flat_partial_stats(grads)
+                    s1 = jax.lax.psum(s1, "model")
+                    s2 = jax.lax.psum(s2, "model")
+                    gbar_i, eps2_i = S.stats_from_partials(s1, s2, ms.d)
+                    if ws_run is not None:
+                        gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
+                else:
+                    gbar_i, eps2_i = jax.vmap(
+                        lambda g: S.flat_scalar_stats(g))(grads)
+                    if ws_run is not None:
+                        # Local per-worker scalars -> full [S, U]: the
+                        # global mean then reduces the same vector the
+                        # unsharded engine reduces (bitwise-equal stats).
+                        gbar_i, eps2_i = ws.gather_stats(gbar_i, eps2_i)
 
-            # 3+4(+5). the shared analog leg: channel draw + coefficients +
-            # OTA combine (+ jam + directional cohort); with wg given it
-            # fuses the PS update too.
-            w_new, gagg = analog_step(w if fused else None, grads, sub_s,
-                                      sp, gbar_i, eps2_i,
-                                      part=part, h_abs=h_abs)
-            if not fused:
-                if digital_select is not None:
+                # 3+4(+5). the shared analog leg: channel draw +
+                # coefficients + OTA combine (+ jam + directional cohort);
+                # with wg given it fuses the PS update too.
+                w_new, gagg = analog_step(w if fused else None, grads, sub_s,
+                                          sp, gbar_i, eps2_i,
+                                          part=part, h_abs=h_abs)
+                if not fused and digital_select is None:
+                    w_new = w - sp.alpha[:, None] * gagg
+            if not fused and digital_select is not None:
+                with _layer(SCOPE_SCREEN):
                     slab = (ws.gather_slab(grads) if ws_run is not None
                             else grads)
                     if ms_run is not None:
@@ -1612,7 +1706,9 @@ class SweepEngine:
                                          gagg, dig)
                     else:
                         gagg = digital_select(gagg, slab, sp, part)
-                w_new = w - sp.alpha[:, None] * gagg
+                    # The select merges every lane's aggregate: the one
+                    # PS update follows it.
+                    w_new = w - sp.alpha[:, None] * gagg
 
             return outputs(w_new, gagg)
 
@@ -1679,19 +1775,20 @@ class SweepEngine:
                 out_specs=(state_spec, lane, lane_t, lane_t, lane_t),
                 check_vma=False)
         if final is None:
-            self._run_jit = jax.jit(run)
+            self._run_jit = jax.jit(_named(run, "sweep_run"))
         else:
             # finalize composes OUTSIDE any shard_map but INSIDE the same
             # jit — it is pure layout (slice/reshape/astype), so the
             # monolithic program's results are unchanged, and under a
             # "model" mesh it sees the global [S, d_pad] state to slice.
-            def run_full(state, keys, batches, sp, _run=run, _final=final):
+            def sweep_run(state, keys, batches, sp, _run=run, _final=final):
                 st, loss, gn, metrics = _run(state, keys, batches, sp)
                 return _final(st), loss, gn, metrics
 
-            self._run_jit = jax.jit(run_full)
-        self._chunk_jit = jax.jit(chunk)
-        self._finalize_jit = None if final is None else jax.jit(final)
+            self._run_jit = jax.jit(sweep_run)
+        self._chunk_jit = jax.jit(_named(chunk, "sweep_chunk"))
+        self._finalize_jit = (None if final is None
+                              else jax.jit(_named(final, "sweep_finalize")))
 
     # ----------------------------------------------------- chunked execution
 
@@ -1786,6 +1883,10 @@ class SweepEngine:
                 jax.tree_util.tree_structure(template),
                 jax.tree_util.tree_leaves(rebuilt))
 
+        # The restored carry is host data staged onto the device, like the
+        # batches: it counts toward H2D_BYTES.
+        self._h2d_bytes += sum(np.asarray(x).nbytes for x in
+                               jax.tree_util.tree_leaves(saved["carry"]))
         state = refit(state, saved["carry"]["state"])
         keys = jnp.asarray(saved["carry"]["keys"])
         if self.mesh is not None:
@@ -1835,12 +1936,14 @@ class SweepEngine:
             # program handles the degenerate stack (lax.scan over length-0
             # xs yields empty [0, S] outputs), keeping chunked == monolithic
             # for every input.
-            return self._run_jit(state, keys, batches, sp)
+            with _span("sweep.dispatch"):
+                return self._run_jit(state, keys, batches, sp)
         t_start = 0
         losses, gns, metric_blocks = [], [], []
         if resume:
-            t_start, state, keys, losses, gns, metric_blocks = \
-                self._restore_checkpoint(rounds, state, keys)
+            with _span("sweep.checkpoint"):
+                t_start, state, keys, losses, gns, metric_blocks = \
+                    self._restore_checkpoint(rounds, state, keys)
         rounds_total = jnp.int32(rounds)
         # Checkpoints land only on chunk boundaries, so t_start is a
         # multiple of chunk_rounds and the remaining blocks slice exactly
@@ -1849,16 +1952,18 @@ class SweepEngine:
         blocks = iter_chunk_blocks(remaining, self.chunk_rounds)
 
         def stage():
-            blk = next(blocks, None)
-            return (None if blk is None
-                    else stage_batch_block(blk, mesh=self.mesh))
+            with _span("sweep.stage"):
+                blk = next(blocks, None)
+                return (None if blk is None else self._staged(
+                    blk, stage_batch_block(blk, mesh=self.mesh)))
 
         nxt = stage() if self.async_staging else None
         every = self.checkpoint_every_chunks
         for i, t0 in enumerate(range(t_start, rounds, self.chunk_rounds)):
             block = nxt if self.async_staging else stage()
-            state, keys, loss, gn, metrics = self._chunk_jit(
-                state, keys, jnp.int32(t0), rounds_total, block, sp)
+            with _span("sweep.dispatch"):
+                state, keys, loss, gn, metrics = self._chunk_jit(
+                    state, keys, jnp.int32(t0), rounds_total, block, sp)
             if self.async_staging:
                 nxt = stage()   # overlaps the in-flight chunk dispatched above
             losses.append(loss)
@@ -1867,19 +1972,24 @@ class SweepEngine:
             t_next = min(t0 + self.chunk_rounds, rounds)
             if (self.checkpoint_dir is not None and t_next < rounds
                     and (i + 1) % every == 0):
-                self._save_checkpoint(t_next, rounds, state, keys,
-                                      losses, gns, metric_blocks)
+                with _span("sweep.checkpoint"):
+                    self._save_checkpoint(t_next, rounds, state, keys,
+                                          losses, gns, metric_blocks)
 
-        params = (state if self._finalize_jit is None
-                  else self._finalize_jit(state))
+        if self._finalize_jit is None:
+            params = state
+        else:
+            with _span("sweep.dispatch"):
+                params = self._finalize_jit(state)
         # Host-side concat along the round axis: per-chunk outputs are
         # [C, S_exec]; the caller's scatter-back/ghost-drop sees the same
         # [R, S_exec] layout the monolithic scan produces.
-        loss = np.concatenate([_fetch(x) for x in losses])
-        gn = np.concatenate([_fetch(x) for x in gns])
-        metrics = {
-            k: np.concatenate([_fetch(m[k]) for m in metric_blocks])
-            for k in (metric_blocks[0] if metric_blocks else {})}
+        with _span("sweep.fetch"):
+            loss = np.concatenate([_fetch(x) for x in losses])
+            gn = np.concatenate([_fetch(x) for x in gns])
+            metrics = {
+                k: np.concatenate([_fetch(m[k]) for m in metric_blocks])
+                for k in (metric_blocks[0] if metric_blocks else {})}
         return params, loss, gn, metrics
 
     # ----------------------------------------------------------------- run
@@ -1904,109 +2014,148 @@ class SweepEngine:
                 "resume=True needs a checkpoint to restore: construct the "
                 "engine with plan=ExecutionPlan(checkpoint_dir=..., "
                 "chunk_rounds=...)")
-        if not params_stacked:
-            params0 = stack_params(params0, self._num)
-        keys = self.spec.keys() if keys is None else jnp.asarray(keys)
-        if self.chunk_rounds is None:
-            batches = jax.tree_util.tree_map(jnp.asarray, batches)
-        else:
-            # Chunked execution stages [C, ...] blocks per chunk; the full
-            # [R, ...] stack stays host-side (numpy views slice for free and
-            # the device never holds more than ~two blocks).
-            batches = jax.tree_util.tree_map(np.asarray, batches)
-
-        template = jax.eval_shape(
-            lambda p: jax.tree_util.tree_map(lambda x: x[0], p), params0)
-        if self._run_jit is None or template != self._template:
-            self._build(template)
-
-        num, total = self._num, self._num + self._pad
-        if self.flat_state:
-            state, _ = flatten_worker_grads(params0, batch_dims=1)  # [S, D] f32
-            if self._ms is not None:
-                # Model sharding: zero-pad D to shards * d_loc ONCE, pre-jit;
-                # ghost columns stay exactly zero for the whole run (the scan
-                # body re-masks every aggregate).  pad_cols acts on the last
-                # axis so it commutes with the lane permute/pad below (axis 0).
-                state = self._ms.pad_cols(state)
-        else:
-            state = params0
-        if self.spec.any_markov:
-            # Gauss-Markov fading: the scan carry grows a [S, U, 2] complex
-            # gain state (stationary init off each lane's base key).  Tuples
-            # thread through permute/pad/device_put/shard specs unchanged —
-            # they are all pytree-prefix operations.
-            state = (state, self._h0_init(keys, self._sp))
-        if self._groups is not None:
-            # Grouped dispatch: gather lanes (and their per-group ghosts)
-            # into LaneGroups execution order; results un-permute below.
-            state = SC.permute_lanes(state, self._groups.perm)
-            keys = SC.permute_lanes(keys, self._groups.perm)
-        else:
-            if self.flat_state:
-                state = SC.pad_lanes(state, total)
-            keys = SC.pad_lanes(keys, total)
-        sp = self._sp_run
-
-        if self.mesh is not None:
-            lane = lane_sharding(self.mesh)
-            rep = replicated_sharding(self.mesh)
-            if self._ms is not None:
-                # The flat [S, d_pad] state splits its column axis over
-                # "model"; the Markov h tuple element (no D axis) stays
-                # lane-sharded like every other operand.
-                wsh = sweep_state_sharding(self.mesh)
-                if self.spec.any_markov:
-                    state = (put_with_sharding(state[0], wsh),
-                             put_with_sharding(state[1], lane))
+        index, self._runs = self._runs, self._runs + 1
+        rounds = jax.tree_util.tree_leaves(batches)[0].shape[0]
+        self._h2d_bytes = 0
+        # One host span per phase, nested in sweep.run: stage the host
+        # inputs, prepare the execution-order carry (and build the programs
+        # on the first run), stage it onto the mesh, dispatch, fetch.
+        with _span("sweep.run", run=index, lanes=self._num, rounds=rounds):
+            with _span("sweep.stage"):
+                params0 = self._staged(
+                    params0, jax.tree_util.tree_map(jnp.asarray, params0))
+                if keys is not None:
+                    keys = self._staged(keys, jnp.asarray(keys))
+                if self.chunk_rounds is None:
+                    batches = self._staged(
+                        batches, jax.tree_util.tree_map(jnp.asarray, batches))
                 else:
-                    state = put_with_sharding(state, wsh)
-            else:
-                state = jax.tree_util.tree_map(
-                    lambda x: put_with_sharding(x, lane), state)
-            keys = put_with_sharding(keys, lane)
-            sp = jax.tree_util.tree_map(
-                lambda x: put_with_sharding(x, lane), sp)
+                    # Chunked execution stages [C, ...] blocks per chunk; the
+                    # full [R, ...] stack stays host-side (numpy views slice
+                    # for free and the device never holds more than ~two
+                    # blocks).
+                    batches = jax.tree_util.tree_map(np.asarray, batches)
+
+            with _span("sweep.prepare"):
+                if not params_stacked:
+                    params0 = stack_params(params0, self._num)
+                if keys is None:
+                    keys = self.spec.keys()
+                template = jax.eval_shape(
+                    lambda p: jax.tree_util.tree_map(lambda x: x[0], p),
+                    params0)
+                if self._run_jit is None or template != self._template:
+                    self._build(template)
+
+                num, total = self._num, self._num + self._pad
+                if self.flat_state:
+                    state, _ = flatten_worker_grads(params0, batch_dims=1)
+                    if self._ms is not None:
+                        # Model sharding: zero-pad D to shards * d_loc ONCE,
+                        # pre-jit; ghost columns stay exactly zero for the
+                        # whole run (the scan body re-masks every aggregate).
+                        # pad_cols acts on the last axis so it commutes with
+                        # the lane permute/pad below (axis 0).
+                        state = self._ms.pad_cols(state)
+                else:
+                    state = params0
+                if self.spec.any_markov:
+                    # Gauss-Markov fading: the scan carry grows a [S, U, 2]
+                    # complex gain state (stationary init off each lane's base
+                    # key).  Tuples thread through permute/pad/device_put/shard
+                    # specs unchanged — all pytree-prefix operations.
+                    state = (state, self._h0_init(keys, self._sp))
+                if self._groups is not None:
+                    # Grouped dispatch: gather lanes (and their per-group
+                    # ghosts) into LaneGroups execution order; results
+                    # un-permute below.
+                    state = SC.permute_lanes(state, self._groups.perm)
+                    keys = SC.permute_lanes(keys, self._groups.perm)
+                else:
+                    if self.flat_state:
+                        state = SC.pad_lanes(state, total)
+                    keys = SC.pad_lanes(keys, total)
+                sp = self._sp_run
+
+            if self.mesh is not None:
+                with _span("sweep.stage"):
+                    lane = lane_sharding(self.mesh)
+                    rep = replicated_sharding(self.mesh)
+                    if self._ms is not None:
+                        # The flat [S, d_pad] state splits its column axis over
+                        # "model"; the Markov h tuple element (no D axis) stays
+                        # lane-sharded like every other operand.
+                        wsh = sweep_state_sharding(self.mesh)
+                        if self.spec.any_markov:
+                            state = (put_with_sharding(state[0], wsh),
+                                     put_with_sharding(state[1], lane))
+                        else:
+                            state = put_with_sharding(state, wsh)
+                    else:
+                        state = jax.tree_util.tree_map(
+                            lambda x: put_with_sharding(x, lane), state)
+                    keys = put_with_sharding(keys, lane)
+                    sp = jax.tree_util.tree_map(
+                        lambda x: put_with_sharding(x, lane), sp)
+                    if self.chunk_rounds is None:
+                        batches = jax.tree_util.tree_map(
+                            lambda x: put_with_sharding(x, rep), batches)
+
             if self.chunk_rounds is None:
-                batches = jax.tree_util.tree_map(
-                    lambda x: put_with_sharding(x, rep), batches)
+                with _span("sweep.dispatch"):
+                    params, loss, gn, metrics = self._run_jit(
+                        state, keys, batches, sp)
+            else:
+                params, loss, gn, metrics = self._run_chunked(
+                    state, keys, batches, sp, resume=resume)
 
-        if self.chunk_rounds is None:
-            params, loss, gn, metrics = self._run_jit(state, keys, batches, sp)
-        else:
-            params, loss, gn, metrics = self._run_chunked(
-                state, keys, batches, sp, resume=resume)
-        if jax.process_count() > 1:
-            # Multi-process fetch edge: the jitted outputs are sharded over
-            # a process-spanning mesh; all-gather them host-side so every
-            # process returns the identical full SweepResult.
-            params = jax.tree_util.tree_map(_fetch, params)
-            loss, gn = _fetch(loss), _fetch(gn)
-            metrics = {k: _fetch(v) for k, v in metrics.items()}
+            with _span("sweep.fetch"):
+                if jax.process_count() > 1:
+                    # Multi-process fetch edge: the jitted outputs are sharded
+                    # over a process-spanning mesh; all-gather them host-side
+                    # so every process returns the identical full SweepResult.
+                    params = jax.tree_util.tree_map(_fetch, params)
+                    loss, gn = _fetch(loss), _fetch(gn)
+                    metrics = {k: _fetch(v) for k, v in metrics.items()}
 
-        if self._groups is not None:
-            # Scatter back to lane order: pick each source lane's execution
-            # row (ghosts are exact replicas; the first occurrence serves).
-            inv = np.asarray(self._groups.inverse)
-            inv_j = jnp.asarray(inv)
+                if self._groups is not None:
+                    # Scatter back to lane order: pick each source lane's
+                    # execution row (ghosts are exact replicas; the first
+                    # occurrence serves).
+                    inv = np.asarray(self._groups.inverse)
+                    inv_j = jnp.asarray(inv)
 
-            def lanes(x):  # scan gives [R, S_exec]
-                return np.asarray(x).T[inv]
+                    def lanes(x):  # scan gives [R, S_exec]
+                        return np.asarray(x).T[inv]
 
-            params_out = jax.tree_util.tree_map(lambda x: x[inv_j], params)
-        else:
-            def lanes(x):  # scan gives [R, S(+ghosts)]: drop the ghost lanes
-                return np.asarray(x).T[:num]
+                    params_out = jax.tree_util.tree_map(lambda x: x[inv_j],
+                                                        params)
+                else:
+                    def lanes(x):  # scan gives [R, S(+ghosts)]: drop ghosts
+                        return np.asarray(x).T[:num]
 
-            params_out = jax.tree_util.tree_map(lambda x: x[:num], params)
+                    params_out = jax.tree_util.tree_map(lambda x: x[:num],
+                                                        params)
 
-        return SweepResult(
-            names=self.spec.names,
-            params=params_out,
-            loss=lanes(loss),
-            grad_norm=lanes(gn),
-            metrics={k: lanes(v) for k, v in metrics.items()},
-        )
+                result = SweepResult(
+                    names=self.spec.names,
+                    params=params_out,
+                    loss=lanes(loss),
+                    grad_norm=lanes(gn),
+                    metrics={k: lanes(v) for k, v in metrics.items()},
+                )
+        jax.monitoring.record_scalar(H2D_BYTES, float(self._h2d_bytes))
+        return result
+
+    def _staged(self, host, dev):
+        """dev, the device copy of the pytree `host`; the bytes of the
+        leaves that came from host (not already device) arrays count
+        toward this run's H2D_BYTES."""
+        for x, y in zip(jax.tree_util.tree_leaves(host),
+                        jax.tree_util.tree_leaves(dev)):
+            if not isinstance(x, jax.Array):
+                self._h2d_bytes += y.nbytes
+        return dev
 
 
 def run_sweep(loss_fn: Callable, params0, batches, spec: SweepSpec,
