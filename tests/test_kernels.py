@@ -66,16 +66,19 @@ def test_floa_step_ref_is_combine_plus_update():
                                   np.asarray(w - alpha[:, None] * want_g))
 
 
-@pytest.mark.parametrize("d,tile_d", [(300, 128), (5000, 2048), (129, 128),
-                                      (127, 128)])
-def test_batched_kernel_pads_non_multiple_d(d, tile_d):
-    """Regression: D not a multiple of TILE_D is padded ONCE outside the
-    jitted core (an earlier version recursed back into the jitted entry with
-    re-padded operands).  Interpret mode, kernel vs oracle."""
+@pytest.mark.parametrize("s,d,tile_d", [
+    (2, 300, 128), (2, 5000, 2048), (2, 129, 128), (2, 127, 128),
+    # S > 8: a ragged lane block (8 + 3 lanes) beside a ragged column block
+    (11, 127, None), (11, 129, None), (11, 5077, None), (11, 5077, 512)])
+def test_batched_kernel_pads_non_multiple_d(s, d, tile_d):
+    """D not a multiple of the column block, and S not a multiple of the
+    lane block: the grid's last blocks are ragged, no operand is padded and
+    no output sliced, and every real entry matches the oracle.  Interpret
+    mode, both batched kernels; tile_d=None takes the derived block."""
     from repro.kernels.floa_aggregate import (floa_aggregate_batched,
                                               floa_step_batched)
-    s, u = 2, 5
-    ks = jax.random.split(jax.random.PRNGKey(d), 7)
+    u = 5
+    ks = jax.random.split(jax.random.PRNGKey(d + s), 7)
     w = jax.random.normal(ks[0], (s, d))
     coeffs = jax.random.normal(ks[1], (s, u))
     grads = jax.random.normal(ks[2], (s, u, d))
@@ -98,6 +101,32 @@ def test_batched_kernel_pads_non_multiple_d(d, tile_d):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(gg), np.asarray(gr),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("s", [1, 2, 16, 272])
+@pytest.mark.parametrize("u", [1, 4, 10, 32])
+def test_batched_blocks_fit_vmem_limit(s, u):
+    """The derived (s_blk, T): a lane block Mosaic accepts (all of S up to
+    8, else 8), T the widest multiple of 128 whose double-buffered blocks
+    fit the budget, under the scoped-VMEM limit the kernels ask for, and
+    each grid step moving at least a megabyte of HBM at a wide D."""
+    from repro.kernels import floa_aggregate as FA
+    assert FA.BATCHED_BLOCK_BUDGET <= FA.BATCHED_VMEM_LIMIT
+    for itemsize in (4, 2):
+        for n_rows in (2, 4):           # aggregate: noise, out; step: +w, w_new
+            s_blk, t = FA.batched_blocks(s, u, 1 << 27, itemsize, n_rows)
+            assert s_blk == min(s, 8)
+            assert t % 128 == 0 and t >= 128
+            vmem = FA.batched_vmem_bytes(s_blk, u, t, itemsize, n_rows)
+            assert vmem <= FA.BATCHED_BLOCK_BUDGET
+            assert FA.batched_vmem_bytes(s_blk, u, t + 128, itemsize,
+                                         n_rows) > FA.BATCHED_BLOCK_BUDGET
+            assert (s_blk * u + n_rows * s_blk) * t * itemsize >= 10 ** 6
+    # sublane padding is counted: U = 1 costs a whole 8-row tile of f32
+    assert (FA.batched_vmem_bytes(1, 1, 128, 4, 0)
+            == FA.batched_vmem_bytes(1, 8, 128, 4, 0) == 2 * 8 * 128 * 4)
+    # a narrow D takes one block no wider than D needs
+    assert FA.batched_blocks(s, u, 300, 4, 4)[1] == 384
 
 
 def test_floa_step_property_random_shapes():

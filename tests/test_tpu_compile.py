@@ -12,6 +12,7 @@ module is imported: only one process at a time may load the TPU library,
 and under pytest-xdist every worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,32 @@ def test_floa_step_batched_compiles(one_chip):
     text = _compile(FA.floa_step_batched, one_chip,
                     (S, D), (S, U), (S, U, D), (S, D), (S,), (S,), (S,))
     assert "tpu_custom_call" in text
+
+
+def _d_wide(text, d, ops):
+    """Instructions of the compiled text whose name starts with one of
+    `ops` and whose result has a dimension of d or more."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%((?:" + "|".join(ops) + r")[\w.-]*) = "
+                     r"(.*?)(?:, metadata=|$)", line)
+        if m and any(int(n) >= d for shape in re.findall(r"\[([\d,]+)\]",
+                                                         m.group(2))
+                     for n in shape.split(",")):
+            found.append(m.group(1))
+    return found
+
+
+def test_floa_step_batched_compiles_at_qwen_width(one_chip):
+    """At the qwen3-4b-ota cell's shapes (D = 150,085,376, not a multiple
+    of any power-of-two tile above 256) the kernel takes its operands as
+    they are: no pad of D and no relayout copy of a D-wide operand or
+    result around the custom call."""
+    s, u, d = 2, 4, 150_085_376
+    text = _compile(FA.floa_step_batched, one_chip,
+                    (s, d), (s, u), (s, u, d), (s, d), (s,), (s,), (s,))
+    assert "tpu_custom_call" in text
+    assert _d_wide(text, d, ("pad", "copy")) == []
 
 
 def test_floa_aggregate_batched_compiles(one_chip):
