@@ -8,7 +8,10 @@ cell's own size, in one process:
             against the reference in float32 (the upper reading);
   faults    the reference with a fault planted (`half_batch`: every worker's
             gradient on half its rows; `unchanged`: the step returns the
-            weights unchanged), against the clean reference.
+            weights unchanged), against the clean reference; on a mesh of
+            lanes also `exchange_left_out`, the program's own result as a
+            gather that read every lane from the first chip's block would
+            return it, against the reference.
 
     python3 perfbench/calibrate.py --workload <name> --seeds 24 --calls 3 \\
         --control-seeds 3 --fault-seeds 3 [--seed-list a,b] [--out readings.jsonl]
@@ -23,13 +26,14 @@ import pathlib
 import sys
 import time
 
+import numpy as np
+
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def as_result(ref: list) -> dict:
     """The reference's lanes in the shape of a program result."""
     import jax
-    import numpy as np
     out = {"loss": np.stack([x["loss"] for x in ref]),
            "grad_norm": np.stack([x["grad_norm"] for x in ref]),
            "accuracy1": (None if ref[0]["accuracy1"] is None
@@ -43,7 +47,6 @@ def lane_table(prog: dict, ref: list, lanes: list, rounds: int) -> dict:
     """Per lane: name, loss and aggregate-norm gaps, and the aggregate norms
     of program and reference in each checked round (the look behind a
     number that swings)."""
-    import numpy as np
     import harness
     ref_gn = np.stack([x["grad_norm"][:rounds] for x in ref])
     return {
@@ -58,6 +61,19 @@ def lane_table(prog: dict, ref: list, lanes: list, rounds: int) -> dict:
     }
 
 
+def first_chip_only(prog: dict, engine):
+    """prog as the program returns it when the gather of every chip's lanes
+    into lane order is left out: each lane is read from the row at the same
+    place in the first chip's block; None where lanes are not split."""
+    groups = None if engine is None else engine._groups
+    if groups is None or groups.shards < 2:
+        return None
+    src = (np.asarray(groups.perm)[np.asarray(groups.inverse)
+                                   % groups.lanes_per_shard])
+    return {k: v if v is None else v[src] for k, v in prog.items()
+            if k != "params"}
+
+
 def readings_for(name: str, seeds, control_seeds, fault_seeds, faults,
                  devices, emit, overrides=None, calls=1):
     import gc
@@ -69,11 +85,11 @@ def readings_for(name: str, seeds, control_seeds, fault_seeds, faults,
     ref_rounds = min(cell.mix.get("ref_rounds", 3), rounds)
     keep = ref_rounds == rounds
 
-    def extra(i, seed, system, call, ref):
+    def extra(i, seed, system, call, ref, prog):
         if i < control_seeds:
             ctl = harness.reference_run(system.model, call["lanes"],
                                         call["keys"], ref_rounds,
-                                        dtype="bfloat16")
+                                        dtype="bfloat16", devices=devices)
             emit({"kind": "control", "seed": seed,
                   **harness.readings(as_result(ctl), ref,
                                      system.model["params0"], ref_rounds,
@@ -84,11 +100,16 @@ def readings_for(name: str, seeds, control_seeds, fault_seeds, faults,
             for fault in faults:
                 bad = harness.reference_run(system.model, call["lanes"],
                                             call["keys"], ref_rounds,
-                                            fault=fault)
+                                            fault=fault, devices=devices)
                 emit({"kind": f"fault:{fault}", "seed": seed,
                       **harness.readings(as_result(bad), ref,
                                          system.model["params0"], ref_rounds,
                                          call["lanes"])})
+            bad = first_chip_only(prog, system.engine)
+            if bad is not None:
+                emit({"kind": "fault:exchange_left_out", "seed": seed,
+                      **harness.readings(bad, ref, system.model["params0"],
+                                         ref_rounds, call["lanes"])})
 
     system = None
     for i, seed in enumerate(seeds):
@@ -109,7 +130,8 @@ def readings_for(name: str, seeds, control_seeds, fault_seeds, faults,
             gc.collect()
             t1 = time.perf_counter()
             ref = harness.reference_run(system.model, call["lanes"],
-                                        call["keys"], ref_rounds)
+                                        call["keys"], ref_rounds,
+                                        devices=devices)
             vals = harness.readings(prog, ref, system.model["params0"],
                                     ref_rounds, call["lanes"])
             emit({"kind": "program", "seed": seed, "call": n + 2, **vals,
@@ -119,7 +141,7 @@ def readings_for(name: str, seeds, control_seeds, fault_seeds, faults,
                   "lanes": lane_table(prog, ref, call["lanes"], ref_rounds)})
             t0 = time.perf_counter()
             if n == 0:
-                extra(i, seed, system, call, ref)
+                extra(i, seed, system, call, ref, prog)
             del ref, prog
             gc.collect()
     jax.clear_caches()

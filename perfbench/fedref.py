@@ -28,6 +28,17 @@ the reported loss is the loss of the new weights on the whole round batch.
 `dtype` runs the whole reference in another precision: bfloat16 is the
 control that must come out as not correct.  `fault` plants one of the
 faults the benchmark's checks are held against.
+
+On a cell of several chips the reference follows each lane over all of
+them (axis "d" of a one-axis mesh): the weights, the aggregate and the
+D-wide draws are split along D; each loss gathers the leaves it reads; each
+chip takes the gradients of its own share of the workers and trades them
+for a block of D of every worker's, so that no chip holds the [U, D]
+gradients.  Between rounds the weights travel zero-padded to a multiple of
+the chip count, so that every chip holds an equal block.  With
+jax_threefry_partitionable (JAX's default) no draw's values depend on the
+split.  On one chip nothing is split and the round program is the plain
+one.
 """
 from __future__ import annotations
 
@@ -38,14 +49,11 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 POLICIES = {"ci": 0, "bev": 1, "ef": 2, "truncated_ci": 3}
 ATTACKS = {"none": 0, "strongest": 1, "sign_flip_protocol_power": 2,
            "gaussian": 3, "colluding": 4, "omniscient": 5}
-
-
-def _flat(tree):
-    return jnp.concatenate([x.reshape(-1) for x in jax.tree_util.tree_leaves(tree)])
 
 
 def _unflat(w, template):
@@ -56,6 +64,17 @@ def _unflat(w, template):
         out.append(w[off:off + n].reshape(leaf.shape))
         off += n
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _split(x, mesh, axis=-1):
+    """x split over the mesh along `axis`: the last, the parameter row, by
+    default; the first, the workers, for the gradients."""
+    if mesh is None:
+        return x
+    spec = [None] * x.ndim
+    spec[axis] = "d"
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, PartitionSpec(*spec)))
 
 
 def _screen(name, f, part, k, num, gm_iters):
@@ -92,7 +111,7 @@ def _screen(name, f, part, k, num, gm_iters):
     raise ValueError(f"unknown defense {name!r}")
 
 
-def _analog(g, w, h, sub, part, k, byz, num, dt):
+def _analog(g, w, h, sub, part, k, byz, num, dt, mesh):
     """Eq. (7) for one lane: standardization stats, the channel, the power
     policy's and the attack's coefficients, bias, noise, jamming and the
     adaptive cohorts' direction.  Every policy and attack is computed and
@@ -131,13 +150,14 @@ def _analog(g, w, h, sub, part, k, byz, num, dt):
     cohort = byz & part
     biased = active & ~ef & (attack != ATTACKS["sign_flip_protocol_power"])
     bias = jnp.where(biased, jnp.sum(jnp.where(cohort, honest, 0)), 0)
-    z = jax.random.normal(ks[1], (d,)).astype(dt)
+    z = _split(jax.random.normal(ks[1], (d,)), mesh).astype(dt)
     agg = s @ g + bias * gbar + eps * jnp.where(ef, 0, num["noise_std"]) * z
     on = active & ~ef
     jam = jnp.sqrt(eps2 * jnp.sum(jnp.where(cohort, amp_bev * h_abs, 0) ** 2))
     agg = agg + jnp.where(on & (attack == ATTACKS["gaussian"]), jam, 0) * \
-        jax.random.normal(ks[2], (d,)).astype(dt)
-    dvec = jax.random.normal(jax.random.fold_in(sub, 3), (d,)).astype(dt)
+        _split(jax.random.normal(ks[2], (d,)), mesh).astype(dt)
+    dvec = _split(jax.random.normal(jax.random.fold_in(sub, 3), (d,)),
+                  mesh).astype(dt)
     dvec = dvec / jnp.maximum(jnp.sqrt(jnp.mean(dvec * dvec)), 1e-20)
     collude = eps * jnp.sum(jnp.where(cohort, amp_bev * h_abs, 0))
     agg = agg + jnp.where(on & (attack == ATTACKS["colluding"]), collude, 0) * dvec
@@ -149,10 +169,12 @@ def _analog(g, w, h, sub, part, k, byz, num, dt):
 
 
 def _round(w, h, key, batch, num, defense, u, gm_iters, loss_fn, template,
-           sizes, dt, fault):
+           sizes, dt, fault, mesh):
     """One round of one lane; `num` holds the lane's numbers and codes.
     Returns the next (w, h, key), the round's loss and aggregate norm, and
     the aggregate's norm per parameter leaf."""
+    if mesh is not None:                           # drop the padding
+        w = _split(w[:sum(sizes)], mesh)
     key, sub = jax.random.split(key)
     byz = jnp.arange(u) < num["attackers"]
 
@@ -162,14 +184,25 @@ def _round(w, h, key, batch, num, defense, u, gm_iters, loss_fn, template,
     b = jax.tree_util.tree_leaves(batch)[0].shape[0] // u
     take = b // 2 if fault == "half_batch" else b
     rows = jax.tree_util.tree_map(                 # worker i: rows [i b, i b + take)
-        lambda x: x.reshape((u, b) + x.shape[1:])[:, :take], batch)
-    g = jax.vmap(jax.grad(worker_loss), in_axes=(None, 0))(w, rows)  # [U, D]
+        lambda x: _split(x.reshape((u, b) + x.shape[1:])[:, :take], mesh, 0),
+        batch)
+    if mesh is None:
+        g = jax.vmap(jax.grad(worker_loss), in_axes=(None, 0))(w, rows)  # [U, D]
+    else:
+        # Each chip takes the gradients of its own workers from the gathered
+        # leaves, then all chips trade them for a block of D of every
+        # worker's.
+        p = _unflat(w, template)
+        g = jax.vmap(lambda r: jnp.concatenate([
+            x.reshape(-1) for x in jax.tree_util.tree_leaves(
+                jax.grad(loss_fn)(p, r))]))(rows)
+        g = _split(_split(g, mesh, 0), mesh)
 
     k = num["participants"]
     sc = jax.random.uniform(jax.random.fold_in(sub, 5), (u,))
     part = jnp.argsort(jnp.argsort(sc)) < k        # all True when k == U
     if defense == "floa":
-        agg, h = _analog(g, w, h, sub, part, k, byz, num, dt)
+        agg, h = _analog(g, w, h, sub, part, k, byz, num, dt, mesh)
     else:
         flip = jnp.where(byz & (num["attack"] != ATTACKS["none"]), -1, 1)
         agg = _screen(defense, g * flip[:, None].astype(dt), part, k, num,
@@ -178,8 +211,16 @@ def _round(w, h, key, batch, num, defense, u, gm_iters, loss_fn, template,
     loss = loss_fn(_unflat(w_new, template), batch)
     a32 = agg.astype(jnp.float32)
     offs = np.cumsum((0,) + sizes)
-    leaf = jnp.stack([jnp.sqrt(jnp.sum(a32[o:o + n] ** 2))
-                      for o, n in zip(offs, sizes)])
+    if mesh is None:
+        leaf = jnp.stack([jnp.sqrt(jnp.sum(a32[o:o + n] ** 2))
+                          for o, n in zip(offs, sizes)])
+    else:   # masked, since a leaf's slice would gather it onto every chip
+        pos = _split(jnp.arange(a32.shape[0]), mesh)
+        leaf = jnp.stack([jnp.sqrt(jnp.sum(jnp.where(
+            (pos >= o) & (pos < o + n), a32 * a32, 0)))
+            for o, n in zip(offs, sizes)])
+        d = w.shape[0]
+        w_new = _split(jnp.pad(w_new, (0, _padded(d, mesh) - d)), mesh)
     return w_new, h, key, loss, jnp.sqrt(jnp.sum(a32 ** 2)), leaf
 
 
@@ -200,23 +241,37 @@ def lane_numbers(lane: dict, dt) -> dict:
     return num
 
 
+def _padded(d: int, mesh) -> int:
+    """The row's length between rounds: d rounded up to the chip count."""
+    return -(-d // mesh.size) * mesh.size
+
+
 class Staged:
-    """What every lane of one reference run shares, made once on the device:
-    the initial weights (flat, in the run's dtype) and the round batches."""
+    """What every lane of one reference run shares, made once on the
+    device: the initial weights (flat, in the run's dtype) and the round
+    batches.  Over several devices the weights are split along D and the
+    batches replicated; the weights are flattened on the host, so that no
+    chip holds a second whole copy beside the program's."""
 
     def __init__(self, params0, batches, rounds: int, dtype=jnp.float32,
-                 cast_batch=None):
+                 cast_batch=None, devices=()):
         self.dt = jnp.dtype(dtype)
-        self.params0 = jax.tree_util.tree_map(
-            lambda x: jnp.asarray(x, self.dt), params0)
-        leaves, self.treedef = jax.tree_util.tree_flatten(self.params0)
+        leaves, self.treedef = jax.tree_util.tree_flatten(params0)
         self.shapes = tuple(tuple(x.shape) for x in leaves)
         self.sizes = tuple(math.prod(x) for x in self.shapes)
-        self.w0 = _flat(self.params0)
+        self.mesh = Mesh(np.array(devices), ("d",)) if len(devices) > 1 else None
+        w0 = np.concatenate([np.asarray(x).reshape(-1) for x in leaves])
+        if self.mesh is None:
+            row, put = None, jnp.asarray
+        else:
+            w0 = np.pad(w0, (0, _padded(w0.size, self.mesh) - w0.size))
+            row = NamedSharding(self.mesh, PartitionSpec("d"))
+            put = functools.partial(jax.device_put, device=NamedSharding(
+                self.mesh, PartitionSpec()))
+        self.w0 = jax.device_put(w0, row).astype(self.dt)
         cast = cast_batch or (lambda b, dt: b)
         self.batches = [cast(jax.tree_util.tree_map(
-            lambda x: jnp.asarray(x[t]), batches), self.dt)
-            for t in range(rounds)]
+            lambda x: put(x[t]), batches), self.dt) for t in range(rounds)]
 
     def unflat_host(self, w: np.ndarray):
         out, off = [], 0
@@ -227,12 +282,13 @@ class Staged:
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled(defense, u, gm_iters, loss_fn, treedef, shapes, sizes, dt, fault):
+def _compiled(defense, u, gm_iters, loss_fn, treedef, shapes, sizes, dt, fault,
+              mesh):
     template = jax.tree_util.tree_unflatten(
         treedef, [jax.ShapeDtypeStruct(s, dt) for s in shapes])
     return jax.jit(functools.partial(
         _round, defense=defense, u=u, gm_iters=gm_iters, loss_fn=loss_fn,
-        template=template, sizes=sizes, dt=dt, fault=fault))
+        template=template, sizes=sizes, dt=dt, fault=fault, mesh=mesh))
 
 
 @functools.lru_cache(maxsize=None)
@@ -262,7 +318,7 @@ def run_lane(lane: dict, key0, staged: Staged, loss_fn: Callable,
     precision = "highest" if dt == jnp.float32 else "default"
     step = _compiled(lane["defense"], lane["num_workers"], lane["gm_iters"],
                      loss_fn, staged.treedef, staged.shapes, staged.sizes, dt,
-                     fault)
+                     fault, staged.mesh)
     num = lane_numbers(lane, dt)
     key = jnp.asarray(key0, jnp.uint32)
     losses, norms, acc1, leaf0 = [], [], None, None

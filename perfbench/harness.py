@@ -211,12 +211,13 @@ def kept_result(res, keep_params: bool) -> dict:
 
 
 def reference_run(model: dict, lanes: list, keys, rounds: int, dtype="float32",
-                  fault=None) -> list:
-    """Follow every lane of one call with the plain reference."""
+                  fault=None, devices=()) -> list:
+    """Follow every lane of one call with the plain reference, over the
+    cell's devices."""
     import fedref
 
     staged = fedref.Staged(model["params0"], model["batches"], rounds, dtype,
-                           model["cast_batch"])
+                           model["cast_batch"], devices)
     return [fedref.run_lane(lane, key, staged, model["ref_loss"], rounds,
                             eval_fn=model["ref_eval"], fault=fault)
             for lane, key in zip(lanes, keys)]
@@ -445,10 +446,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         last = None
         prog = kept_result(res, keep_params)
         del res
+        # The weights leave the chip: the reference stages its own copy,
+        # split over the cell's chips.
+        model["params0"] = jax.device_get(model["params0"])
         gc.collect()
         jax.clear_caches()
         t_ref = time.perf_counter()
-        ref = reference_run(model, call["lanes"], call["keys"], ref_rounds)
+        ref = reference_run(model, call["lanes"], call["keys"], ref_rounds,
+                            devices=devices)
         values = readings(prog, ref, model["params0"], ref_rounds,
                           call["lanes"])
         log(f"reference: {len(ref)} lanes x {ref_rounds} rounds in "

@@ -9,9 +9,10 @@ each of S lanes the fused OTA combine and parameter-server step over a
   flops  per lane and column: U multiply-adds (2 U), the bias, the noise
          multiply-add and the step's multiply-add (5): (2 U + 5) S D
 
-Bound: bytes (about 0.4 FLOP per byte at U = 4 or 10).  D is the width the
-kernel is called on, padded to a multiple of its 2048-wide tile (its HLO
-shapes)."""
+Bound: bytes (about 0.4 FLOP per byte at U = 4 or 10).  D is the true,
+unpadded width the kernel is called on: its [S, U, D] slab and [S, D] rows
+in the HLO shapes, with a ragged last block where D is no multiple of the
+kernel's tile."""
 
 def cost(lanes: int, u: int, d: int, itemsize: int = 4) -> dict:
     return {"bytes": (u + 4) * lanes * d * itemsize,

@@ -2,8 +2,8 @@
 kernel's share of its roofline, 100 x (least time its bytes need at the
 chip's HBM bandwidth) / (its summed device time in the trace), over every
 launch in the window.  A launch reads an f32[S, U, D] gradient slab (its
-largest operand in the HLO text, D padded to the kernel's tile) with the
-[S, 1, D] weight and noise rows.  Bound: bytes."""
+largest 3-D operand in the HLO text, D the true width, unpadded) with the
+[S, D] weight and noise rows.  Bound: bytes."""
 from _roofline import read_kernel
 
 

@@ -110,6 +110,17 @@ def _break(monkeypatch, fault, name):
     elif fault == "half_batch":
         monkeypatch.setattr(sweep, "per_worker_grads",
                             _half_rows(sweep.per_worker_grads))
+    elif fault == "exchange_left_out":
+        # The lanes of chips 1..3 are read back from chip 0's block: the
+        # gather of every chip's results into lane order is left out.
+        import dataclasses
+        orig = sweep.SC.build_lane_groups
+
+        def first_chip_only(codes, shards):
+            g = orig(codes, shards)
+            return dataclasses.replace(g, inverse=tuple(
+                i % g.lanes_per_shard for i in g.inverse))
+        monkeypatch.setattr(sweep.SC, "build_lane_groups", first_chip_only)
     elif fault == "answer_altered":
         if name == "showdown-seeds":
             import repro.models as models
@@ -169,5 +180,21 @@ def test_a_run_with_a_broken_path_is_not_correct(monkeypatch, name, fault):
     _break(monkeypatch, fault, name)
     line = harness.run_cell(name, 5, 0.01, False, 0.0, jax.devices()[:1],
                             overrides=SMALL[name])
+    assert line["checks"], line
+    assert line["correct"] is (fault is None), line
+
+
+@pytest.mark.parametrize("fault", [None, "unchanged", "half_batch",
+                                   "answer_altered", "exchange_left_out"])
+def test_a_four_chip_run_with_a_broken_path_is_not_correct(monkeypatch,
+                                                           fault):
+    """showdown-seeds-data4 on four devices, the reference split over them."""
+    name = "showdown-seeds-data4"
+    _break(monkeypatch, fault, "showdown-seeds")
+    small = SMALL["showdown-seeds"]
+    overrides = {**small, "mix": {**small["mix"], "mesh": {}}}
+    line = harness.run_cell(name, 5, 0.01, False, 0.0, jax.devices()[:4],
+                            overrides=overrides)
+    assert line["device"]["count"] == 4, line
     assert line["checks"], line
     assert line["correct"] is (fault is None), line
